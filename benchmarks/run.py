@@ -36,7 +36,10 @@ def main() -> None:
                     help="directory for the per-suite BENCH_<suite>.json")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
     from repro.obs import trace
+
+    use_compile_cache()
 
     from . import (accuracy_pairs, adaptive_bloom, algo_speedup, common,
                    construction, engine_bench, heuristics, kernels_bench,

@@ -5,9 +5,9 @@ strong scaling is not measurable; we report the two scaling axes we can:
     proxy; the paper grows m with threads). Exact galloping degrades with
     the d_max growth of power-law graphs while PG stays ~linear in m —
     the load-balance argument of Fig. 1 panel 5 in measurable form.
-  * device scaling: shard_map mining on 1..8 fake host devices (launch.mine)
-    is exercised in tests/test_system.py; on real hardware that path is the
-    strong-scaling story.
+  * device scaling: the engine's edge-sharded TC fold on 1..8 fake host
+    devices (launch.mine) is exercised in tests/test_system.py; on real
+    hardware that path is the strong-scaling story.
 """
 from __future__ import annotations
 

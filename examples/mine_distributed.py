@@ -1,9 +1,9 @@
 """Distributed ProbGraph mining demo (the paper's workload on a device mesh).
 
-Spawns 8 host devices, builds Bloom sketches with a vertex-sharded
-shard_map, runs edge-sharded triangle counting with psum combining, and
-compares against the exact count. The same code path targets the 16×16 pod
-mesh (launch/mine.py).
+Spawns 8 host devices, builds Bloom sketches once, runs the engine's
+edge-sharded triangle count (``EnginePlan.shard_edges``: a shard_map over
+every mesh axis with a psum), and compares against the exact count. The
+same code path runs on a multi-chip TPU mesh (launch/mine.py).
 
 Run:  PYTHONPATH=src python examples/mine_distributed.py
 """

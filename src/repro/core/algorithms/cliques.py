@@ -102,7 +102,7 @@ def four_clique_count(graph: Graph, sketch: Optional[SketchSet] = None,
             b = sketch.num_hashes
             total_bits = sketch.data.shape[1] * 32
             w_safe = jnp.where(tri, nv, 0)
-            # engine's 3-way popcount provider: block-gather kernel when
+            # engine's 3-way popcount provider: fused Pallas pass when
             # planned, broadcast jnp gather otherwise
             ones = eng.wedge_triple_ones(sketch, u, v, w_safe, plan)
             triple = est.bf_intersection_and_from_ones(ones, total_bits, b)

@@ -26,7 +26,7 @@ def make_pair_cardinality_fn(graph: Graph, sketch: Optional[SketchSet] = None,
                              *, use_kernel: bool = False,
                              variant: str = "union",
                              estimator: Optional[str] = None,
-                             block_e: int = 8, block_w: int = 512) -> CardFn:
+                             block_e: int = 256, block_w: int = 512) -> CardFn:
     """Build the batched pairs[P, 2] -> float32[P] cardinality provider."""
     if sketch is None:
         def exact_fn(pairs: jax.Array) -> jax.Array:

@@ -4,14 +4,15 @@ Responsibilities (SISA's set-centric batching + GBBS's shared primitives):
 
   * ``pair_cardinality_fn``  — the |N_u ∩ N_v| provider, plan-dispatched
     between the exact galloping baseline, jnp estimator paths, and the
-    block-gather Pallas kernels.
+    fused Pallas popcount pass.
   * ``edge_cardinalities`` / ``sum_edge_cardinalities`` — chunked per-edge
     map / fold over an edge list with degree-ordered layout and optional
     shard_map over the edge axis (repro.distributed.sharding rules).
   * ``tuple_cardinality_ones`` / ``triple_cardinality_ones`` — the k-way
     popcount provider over row-index tuples, compiled from the k-way AND
-    set expression (``repro.engine.setexpr``) to one fused block-gather
-    pass or the equivalent jnp gather (bit-identical popcounts).
+    set expression (``repro.engine.setexpr``) to one fused Pallas pass
+    over gathered rows or the equivalent jnp gather (bit-identical
+    popcounts).
   * ``session`` — multi-query amortization: build the sketch once, run
     TC + LCC + clustering + 4-clique over the shared sketch and the shared
     per-edge cardinality pass.
@@ -25,6 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from ..core.graph import Graph
 from ..core.intersect import CardFn, make_pair_cardinality_fn
@@ -72,10 +74,11 @@ def edge_cardinalities(graph: Graph, sketch: Optional[SketchSet],
     """
     fn = pair_cardinality_fn(graph, sketch, plan)
     edges = graph.edges if edges is None else edges
+    mapper = _sharded_map if plan.shard_edges else map_edges
     if plan.degree_order and edges.shape[0] > 1:
         edges_s, inv = order_edges_by_hub(graph, edges)
-        return jnp.take(map_edges(edges_s, fn, plan), inv)
-    return map_edges(edges, fn, plan)
+        return jnp.take(mapper(edges_s, fn, plan), inv)
+    return mapper(edges, fn, plan)
 
 
 def sum_edge_cardinalities(graph: Graph, sketch: Optional[SketchSet],
@@ -96,22 +99,25 @@ def sum_edge_cardinalities(graph: Graph, sketch: Optional[SketchSet],
     return fold_edges(edges, chunk, plan)
 
 
-def _sharded_fold(edges: jax.Array, chunk_fn, plan: EnginePlan) -> jax.Array:
-    """shard_map the masked edge fold over the active mesh's edge axes.
+def _edge_shards(edges: jax.Array, plan: EnginePlan):
+    """Split the edge axis over the active mesh's edge axes.
 
-    Falls back to the local fold when no mesh is active. Fixed-size sketch
-    rows mean every shard does identical work — the paper's no-straggler
-    property — so a plain psum closes the reduction.
+    Returns ``(mesh, axes, edges_p, mask)`` with the edge list zero-padded
+    so every shard holds whole ``edge_chunk`` chunks, or None when no mesh
+    is active or the rules map "edge" to no mesh axis.
     """
-    from jax.experimental.shard_map import shard_map
-
     mesh = sharding.active_mesh()
     if mesh is None:
-        return fold_edges(edges, chunk_fn, plan)
-    spec = sharding.spec_for(("edge", None), mesh=mesh)
-    axes = spec[0]
+        return None
+    # the split is internal: callers pass and get back arrays placed
+    # anywhere, so the shard_map runs with Auto axes even on a mesh whose
+    # axes are Explicit (their sharded types would leak into single-device
+    # consumers of the per-edge output, such as the LCC scatter)
+    mesh = Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+    axes = sharding.spec_for(("edge",), mesh=mesh)[0]
     if axes is None:
-        return fold_edges(edges, chunk_fn, plan)
+        return None
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
     nshards = int(np.prod([mesh.shape[a] for a in axes]))
     m = edges.shape[0]
@@ -119,19 +125,44 @@ def _sharded_fold(edges: jax.Array, chunk_fn, plan: EnginePlan) -> jax.Array:
     edges_p = jnp.concatenate(
         [edges, jnp.zeros((pad, edges.shape[1]), edges.dtype)], axis=0)
     mask = jnp.concatenate([jnp.ones(m, bool), jnp.zeros(pad, bool)])
+    return mesh, axes, edges_p, mask
 
-    mask_spec = jax.sharding.PartitionSpec(spec[0])
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec, mask_spec),
-                       out_specs=jax.sharding.PartitionSpec())
+def _sharded_fold(edges: jax.Array, chunk_fn, plan: EnginePlan) -> jax.Array:
+    """shard_map the masked edge fold over the active mesh's edge axes.
+
+    Falls back to the local fold when no mesh is active. Fixed-size sketch
+    rows mean every shard does identical work — the paper's no-straggler
+    property — so a plain psum closes the reduction.
+    """
+    shards = _edge_shards(edges, plan)
+    if shards is None:
+        return fold_edges(edges, chunk_fn, plan)
+    mesh, axes, edges_p, mask = shards
+
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(axes, None),
+                                                          P(axes)),
+                       out_specs=P())
     def fold_shard(edge_shard, mask_shard):
         """Per-shard fold, psum-reduced over the edge axes."""
-        local = fold_edges_masked(edge_shard, mask_shard, chunk_fn, plan)
-        for ax in axes:
-            local = jax.lax.psum(local, ax)
-        return local
+        local = fold_edges_masked(edge_shard, mask_shard, chunk_fn, plan,
+                                  vary_axes=axes)
+        return jax.lax.psum(local, axes)
 
     return fold_shard(edges_p, mask)
+
+
+def _sharded_map(edges: jax.Array, fn, plan: EnginePlan) -> jax.Array:
+    """shard_map the chunked per-edge map over the active mesh's edge axes;
+    the result stays split over the edge shards (local map without a
+    mesh)."""
+    shards = _edge_shards(edges, plan)
+    if shards is None:
+        return map_edges(edges, fn, plan)
+    mesh, axes, edges_p, _ = shards
+    out = jax.shard_map(lambda e: map_edges(e, fn, plan), mesh=mesh,
+                        in_specs=P(axes, None), out_specs=P(axes))(edges_p)
+    return out[:edges.shape[0]]
 
 
 def tuple_cardinality_ones(sketch: SketchSet, tuples: jax.Array,
@@ -140,7 +171,7 @@ def tuple_cardinality_ones(sketch: SketchSet, tuples: jax.Array,
 
     The plan-dispatched face of the set-expression compiler for the common
     k-way AND: ``tuples`` is int32[T, k] and the cached compiled expression
-    lowers to one fused block-gather pass (``plan.use_kernel``) or the
+    lowers to one fused Pallas pass (``plan.use_kernel``) or the
     equivalent jnp gather. Both produce identical popcounts, so downstream
     estimates are bit-identical.
     """
@@ -168,8 +199,8 @@ def wedge_triple_ones(sketch: SketchSet, u: jax.Array, v: jax.Array,
     """popcnt(Bu & Bv & Bw) over a wedge grid: u, v int32[C], w int32[C, d]
     -> int32[C, d] (the 4-clique triple-intersection provider).
 
-    Kernel path flattens to (u, v, w) triples for the 3-way block-gather
-    kernel; the jnp path keeps the broadcast form so the u/v rows are
+    Kernel path flattens to (u, v, w) triples for the 3-way fused pass;
+    the jnp path keeps the broadcast form so the u/v rows are
     gathered once per edge rather than once per wedge. Identical integer
     popcounts either way.
     """
@@ -489,9 +520,8 @@ class MiningSession:
             # gather from the stable-shape buffer so the compiled gather is
             # reused across deltas; padded positions hit sentinel rows whose
             # (garbage) cardinalities the fused scatter below drops. Clamp
-            # the sentinel vertex id n to a real row first: the Pallas
-            # kernel path DMAs rows by raw index and must never see an
-            # out-of-bounds one (the jnp path would merely clip).
+            # the sentinel vertex id n to a real row first, so every path
+            # gathers real sketch rows.
             sub_edges = jnp.minimum(
                 jnp.take(dc.edges_full, dc.recompute_pos, axis=0),
                 jnp.int32(max(self.graph.n - 1, 0)))

@@ -26,9 +26,9 @@ class EnginePlan:
 
     Attributes:
       edge_chunk:   edges per scan-fold step (HBM working-set knob).
-      block_e:      Bloom-row pairs gathered per Pallas grid step.
-      block_w:      sketch words per Pallas grid step.
-      use_kernel:   route BF popcounts through the block-gather Pallas kernels.
+      block_e:      tuples per grid step of the fused Pallas popcount pass.
+      block_w:      sketch words per grid step of the fused pass.
+      use_kernel:   route BF popcounts through the fused Pallas pass.
       degree_order: sort edge blocks by hub endpoint so high-degree rows are
                     revisited by consecutive blocks (VMEM/HBM-stream reuse).
       estimator:    estimator override (e.g. "bf_l" on a "bf" sketch).
@@ -41,7 +41,8 @@ class EnginePlan:
                     ``[S, n]`` residual tensors, "sparse" stores per-seed
                     support in capped ``[S, cap]`` index+value buffers, and
                     "auto" (default) picks sparse only when the cap implied
-                    by ``1/(alpha·eps)`` is far enough below ``n`` to pay.
+                    by ``1/(alpha·eps)``, times the adjacency rows each slot
+                    gathers, is far enough below ``n`` to pay.
       frontier_cap: explicit sparse-frontier capacity override (entries per
                     seed; pow2-bucketed). ``None`` sizes it from the ACL
                     support bound ``O(1/(alpha·eps))``. Undersizing is safe:
@@ -49,7 +50,7 @@ class EnginePlan:
     """
 
     edge_chunk: int = 65536
-    block_e: int = 8
+    block_e: int = 256
     block_w: int = 512
     use_kernel: bool = False
     degree_order: bool = False
@@ -134,9 +135,15 @@ def _pad_edges(edges: jax.Array, chunk: int):
 
 
 def fold_edges_masked(edges: jax.Array, mask: jax.Array, chunk_fn,
-                      plan: EnginePlan) -> jax.Array:
+                      plan: EnginePlan,
+                      vary_axes: Tuple[str, ...] = ()) -> jax.Array:
     """Scan-fold of ``chunk_fn(pairs, mask) -> scalar`` with a caller-supplied
-    validity mask; ``edges`` must already be chunk-padded when chunked."""
+    validity mask; ``edges`` must already be chunk-padded when chunked.
+
+    Inside ``shard_map`` the chunk sums vary over the mesh axes the edges
+    are split on; ``vary_axes`` names them so the scan carry starts out
+    varying over the same axes.
+    """
     m = edges.shape[0]
     if m == 0:
         return jnp.float32(0)
@@ -148,8 +155,11 @@ def fold_edges_masked(edges: jax.Array, mask: jax.Array, chunk_fn,
         pairs, msk = xs
         return c + chunk_fn(pairs, msk), None
 
+    init = jnp.float32(0)
+    if vary_axes:
+        init = jax.lax.pcast(init, vary_axes, to="varying")
     total, _ = jax.lax.scan(
-        body, jnp.float32(0),
+        body, init,
         (edges.reshape(-1, plan.edge_chunk, edges.shape[1]),
          mask.reshape(-1, plan.edge_chunk)))
     return total

@@ -6,12 +6,11 @@ per workload — is the right abstraction. This module is that instruction
 set: a tiny IR of :class:`SetExpr` nodes (k-way ``AND``/``OR``/``ANDNOT``
 over sketch rows, implicitly popcount-reduced) and a compiler that lowers
 any expression tree to **one** fused Pallas VMEM pass
-(:mod:`repro.kernels.fused_expr`) — block-gather DMA of every referenced
-sketch row per tuple block, bitwise evaluation in registers, popcount
-reduction — or to the equivalent jnp gather when the plan stays off the
-kernel path. Kernel and jnp lowerings evaluate the *same* expression
-closure on the same integers, so their popcounts are bit-identical by
-construction.
+(:mod:`repro.kernels.fused_expr`) — the referenced sketch rows gathered
+per tuple, bitwise evaluation in registers, popcount reduction — or to the
+equivalent jnp gather when the plan stays off the kernel path. Kernel and
+jnp lowerings evaluate the *same* expression closure on the same integers,
+so their popcounts are bit-identical by construction.
 
 The three formerly hand-rolled kernels are expressions here::
 
@@ -42,6 +41,7 @@ import jax.numpy as jnp
 
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
+from ..kernels import fused_expr
 from ..obs import trace
 from ..obs.metrics import REGISTRY
 from .plan import pow2_bucket
@@ -207,11 +207,6 @@ def _make_eval(expr: SetExpr, pos: Dict[int, int]
 # the compiler
 # ----------------------------------------------------------------------------
 
-def _default_interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPU backends."""
-    return jax.default_backend() != "tpu"
-
-
 def _pad_axis0(x: jax.Array, to: int, fill=0) -> jax.Array:
     """Zero-fill (or ``fill``-fill) the leading axis up to length ``to``."""
     pad = to - x.shape[0]
@@ -219,15 +214,6 @@ def _pad_axis0(x: jax.Array, to: int, fill=0) -> jax.Array:
         return x
     return jnp.concatenate(
         [x, jnp.full((pad, *x.shape[1:]), fill, x.dtype)], axis=0)
-
-
-def _pad_words(x: jax.Array, mult: int) -> jax.Array:
-    """Zero-pad the word axis to a multiple of ``mult`` (no bits added)."""
-    pad = (-x.shape[-1]) % mult
-    if pad == 0:
-        return x
-    return jnp.concatenate(
-        [x, jnp.zeros((*x.shape[:-1], pad), x.dtype)], axis=-1)
 
 
 class CompiledSetExpr:
@@ -245,9 +231,9 @@ class CompiledSetExpr:
         computed rather than resident in the sketch matrix, like the sweep
         cut's prefix filter).
 
-    The tuple/row axis is padded to a pow2 bucket (then to a ``block_e``
-    multiple) so varying workload sizes share compiled programs; the word
-    axis pads with zero words, which add no bits to any popcount.
+    The tuple/row axis is padded to a pow2 bucket so varying workload
+    sizes share compiled programs; the kernel pads the word axis with zero
+    words, which add no bits to any popcount.
     """
 
     def __init__(self, expr: SetExpr, *, block_e: int, block_w: int,
@@ -260,7 +246,7 @@ class CompiledSetExpr:
         self.block_e = int(block_e)
         self.block_w = int(block_w)
         self.use_kernel = bool(use_kernel)
-        self.interpret = (_default_interpret() if interpret is None
+        self.interpret = (fused_expr.default_interpret() if interpret is None
                           else bool(interpret))
         self._eval = _make_eval(expr, {s: i for i, s in enumerate(self.slots)})
         self._ones_jit = jax.jit(self._ones_impl)
@@ -272,16 +258,12 @@ class CompiledSetExpr:
         """Padded lowering of the gather form (jitted per input shape)."""
         t = tuples.shape[0]
         if self.use_kernel:
-            from ..kernels import fused_expr
-
-            t_b = pow2_bucket(t)
-            be = min(self.block_e, t_b)
-            t_pad = -(-t_b // be) * be
-            bw = min(self.block_w, data.shape[1])
-            cols = [_pad_axis0(tuples[:, s], t_pad) for s in self.slots]
+            # pad with row 0 (always present); the pad's counts are sliced off
+            cols = [_pad_axis0(tuples[:, s], pow2_bucket(t))
+                    for s in self.slots]
             out = fused_expr.fused_gather_popcount(
-                _pad_words(data, bw), cols, self._eval, block_e=be,
-                block_w=bw, interpret=self.interpret)
+                data, cols, self._eval, block_e=self.block_e,
+                block_w=self.block_w, interpret=self.interpret)
             return out[:t]
         vals = tuple(jnp.take(data, tuples[:, s], axis=0)
                      for s in self.slots)
@@ -322,21 +304,12 @@ class CompiledSetExpr:
 
     def _ones_rows_impl(self, *rows: jax.Array) -> jax.Array:
         """Padded lowering of the dense form (jitted per input shape)."""
-        e, w = rows[0].shape
+        e = rows[0].shape[0]
         if self.use_kernel:
-            from ..kernels import fused_expr
-
-            e_b = pow2_bucket(e)
-            be = min(self.block_e, e_b)
-            e_pad = -(-e_b // be) * be
-            w2 = w + (w % 2)                     # lane-friendly even width
-            bw = min(self.block_w, w2)
-            w_pad = -(-w2 // bw) * bw
-            padded = [jnp.pad(_pad_axis0(r, e_pad), ((0, 0), (0, w_pad - w)))
-                      for r in rows]
+            padded = [_pad_axis0(r, pow2_bucket(e)) for r in rows]
             out = fused_expr.fused_rows_popcount(
-                padded, self._eval, block_e=be, block_w=bw,
-                interpret=self.interpret)
+                padded, self._eval, block_e=self.block_e,
+                block_w=self.block_w, interpret=self.interpret)
             return out[:e]
         return jnp.sum(jax.lax.population_count(self._eval(tuple(rows))),
                        axis=-1).astype(jnp.int32)
@@ -366,7 +339,7 @@ _CACHE: Dict[tuple, CompiledSetExpr] = {}
 _CACHE_HITS = 0
 
 
-def compile_expr(expr: SetExpr, *, block_e: int = 8, block_w: int = 512,
+def compile_expr(expr: SetExpr, *, block_e: int = 256, block_w: int = 512,
                  use_kernel: bool = True,
                  interpret: Optional[bool] = None) -> CompiledSetExpr:
     """Compile (with caching) a set expression to a fused popcount pass.

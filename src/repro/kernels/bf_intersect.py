@@ -1,40 +1,19 @@
-"""Pallas TPU kernels for Bloom-filter set algebra (ProbGraph hot loop).
+"""Private fixed-arity Bloom AND+popcount entry points (deprecated names).
 
-The paper's CPU hot loop is `popcnt(AND(Bx, By))` over AVX lanes; the TPU
-adaptation runs it on the VPU (8×128 lanes) with explicit VMEM tiling:
+The paper's CPU hot loop is `popcnt(AND(Bx, By))` over AVX lanes; on the
+TPU it runs on the VPU as the fused dense pass in ``fused_expr.py``. This
+module keeps the fixed-arity forms that predate the set-expression compiler:
 
-  * ``bf_intersect_pairs_kernel``: dense [E, W] x [E, W] -> [E] AND+popcount,
-    tiled (block_e × block_w), accumulating over the word-tile grid axis.
-    This is the roofline-friendly form: arithmetic intensity is fixed
-    (1 AND + 1 popcount + 1 add per 8 bytes), so the kernel is HBM-bound and
-    tiles are chosen to stream at full bandwidth.
+  * ``_pairs_impl`` / ``_pairs3_impl``: dense [E, W] row pairs (triples)
+    -> int32[E] AND+popcount.
+  * ``_edge_impl`` / ``_edge3_impl``: sketch rows gathered by an edge
+    (triple) index list -> int32[E] AND+popcount.
 
-  * ``bf_edge_intersect``: the block-gather form (SISA-style: many set
-    operations per issued grid step). The edge list lives in SMEM via
-    PrefetchScalarGridSpec; each (block_e, block_w) grid step issues
-    ``block_e`` row-pair DMAs from the sketch matrix (kept in ANY/HBM) into
-    VMEM scratch slabs and AND+popcounts the whole slab in one VPU pass.
-    Compared to the earlier per-edge form (grid=(E, W/block_w), two (1,
-    block_w) slabs per step) this amortizes grid/DMA issue overhead over
-    ``block_e`` edges and lets degree-ordered edge blocks (see
-    ``repro.engine.plan.order_edges_by_hub``) reuse hub rows that are already
-    resident in the same slab's HBM stream.
-
-  * ``bf_edge_intersect3``: the 3-way block-gather variant for 4-clique
-    triple intersections popcnt(Bu AND Bv AND Bw) over (u, v, w) triples.
-
-Callers must pad: E to a multiple of ``block_e`` (pad edges with (0, 0) —
-row 0 always exists and results are sliced off) and W to a multiple of
-``block_w`` (zero words contribute no bits). ``repro.kernels.ops`` does both.
-
-These raw kernels are now *private* (``_pairs_impl``/``_edge_impl`` family):
-the public seam is ``repro.kernels.ops``, whose entrypoints compile the
-equivalent set expression (``repro.engine.setexpr``) down to the generalized
-fused pass in ``fused_expr.py``. The old public names here remain importable
-as ``DeprecationWarning`` shims, and the private impls double as the golden
-oracles the bit-identity tests compare the compiled expressions against.
-
-All kernels validate in interpret mode against ``ref.py`` (see tests).
+Each is a direct call of the fused pass with a literal AND, bypassing the
+compiler's cache and pow2 padding, which is what makes them the golden
+oracles the bit-identity tests compare compiled expressions against. The
+public seam is ``repro.kernels.ops``; the old public names here remain
+importable as ``DeprecationWarning`` shims.
 """
 from __future__ import annotations
 
@@ -42,213 +21,45 @@ import functools
 import warnings
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .fused_expr import fused_gather_popcount, fused_rows_popcount
 
 
-# ----------------------------------------------------------------------------
-# dense pairs kernel
-# ----------------------------------------------------------------------------
-
-def _pairs_kernel(a_ref, b_ref, o_ref):
-    """AND+popcount one (block_e, block_w) tile pair, accumulating over j."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    cnt = jax.lax.population_count(a_ref[...] & b_ref[...])
-    o_ref[...] += jnp.sum(cnt.astype(jnp.int32), axis=1)
+def _and(vals):
+    """Literal k-way AND of the operand word arrays."""
+    return functools.reduce(lambda x, y: x & y, vals)
 
 
 def _pairs_impl(a: jax.Array, b: jax.Array, *, block_e: int = 256,
                 block_w: int = 512, interpret: bool = False) -> jax.Array:
-    """uint32[E, W] x uint32[E, W] -> int32[E]; E, W already block-padded."""
-    e, w = a.shape
-    block_e = min(block_e, e)
-    block_w = min(block_w, w)
-    grid = (pl.cdiv(e, block_e), pl.cdiv(w, block_w))
-    return pl.pallas_call(
-        _pairs_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_e, block_w), lambda i, j: (i, j)),
-            pl.BlockSpec((block_e, block_w), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((block_e,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
-        interpret=interpret,
-    )(a, b)
-
-
-def _pairs3_kernel(a_ref, b_ref, c_ref, o_ref):
-    """3-way AND+popcount one tile triple, accumulating over j."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    cnt = jax.lax.population_count(a_ref[...] & b_ref[...] & c_ref[...])
-    o_ref[...] += jnp.sum(cnt.astype(jnp.int32), axis=1)
+    """uint32[E, W] x uint32[E, W] -> int32[E]."""
+    return fused_rows_popcount((a, b), _and, block_e=block_e,
+                               block_w=block_w, interpret=interpret)
 
 
 def _pairs3_impl(a: jax.Array, b: jax.Array, c: jax.Array, *,
                  block_e: int = 256, block_w: int = 512,
                  interpret: bool = False) -> jax.Array:
     """3-way dense variant of :func:`_pairs_impl` -> int32[E]."""
-    e, w = a.shape
-    block_e = min(block_e, e)
-    block_w = min(block_w, w)
-    grid = (pl.cdiv(e, block_e), pl.cdiv(w, block_w))
-    spec = pl.BlockSpec((block_e, block_w), lambda i, j: (i, j))
-    return pl.pallas_call(
-        _pairs3_kernel,
-        grid=grid,
-        in_specs=[spec, spec, spec],
-        out_specs=pl.BlockSpec((block_e,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
-        interpret=interpret,
-    )(a, b, c)
+    return fused_rows_popcount((a, b, c), _and, block_e=block_e,
+                               block_w=block_w, interpret=interpret)
 
 
-# ----------------------------------------------------------------------------
-# block-gather edge kernels (scalar-prefetched edge list, manual row DMA)
-# ----------------------------------------------------------------------------
-
-def _gather_rows(ids_ref, base, bloom_ref, bufs, sems, *, count, block_w, j):
-    """DMA `count` sketch rows (word slab j) into the VMEM scratch slabs.
-
-    ids_ref is a tuple of SMEM-prefetched index arrays (one per slab). All
-    row copies are started first and waited on afterwards, so the per-row
-    fetches pipeline: the whole (count × len(bufs)) DMA burst is in flight
-    at once instead of serializing row by row.
-    """
-    def row_copies(r):
-        """The per-slab async copies fetching row ``r`` of this burst."""
-        return [pltpu.make_async_copy(
-            bloom_ref.at[ids[base + r], pl.ds(j * block_w, block_w)],
-            buf.at[r], sems.at[s])
-            for s, (ids, buf) in enumerate(zip(ids_ref, bufs))]
-
-    def start(r, carry):
-        """fori_loop body: launch row ``r``'s copies without blocking."""
-        for cp in row_copies(r):
-            cp.start()
-        return carry
-
-    def wait(r, carry):
-        """fori_loop body: block until row ``r``'s copies have landed."""
-        for cp in row_copies(r):
-            cp.wait()
-        return carry
-
-    jax.lax.fori_loop(0, count, start, 0)
-    jax.lax.fori_loop(0, count, wait, 0)
-
-
-def _edge_block_kernel(u_ref, v_ref, bloom_ref, o_ref, a_buf, b_buf, sems, *,
-                       block_e, block_w):
-    """Gather block_e row pairs, AND+popcount the slabs, accumulate over j."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    _gather_rows((u_ref, v_ref), i * block_e, bloom_ref, (a_buf, b_buf), sems,
-                 count=block_e, block_w=block_w, j=j)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    cnt = jax.lax.population_count(a_buf[...] & b_buf[...])
-    o_ref[...] += jnp.sum(cnt.astype(jnp.int32), axis=1)
-
-
-def _edge_impl(bloom: jax.Array, edges: jax.Array, *, block_e: int = 8,
+def _edge_impl(bloom: jax.Array, edges: jax.Array, *, block_e: int = 256,
                block_w: int = 512, interpret: bool = False) -> jax.Array:
-    """uint32[n, W] sketch matrix + int32[E, 2] edges -> int32[E].
-
-    Block-gather: grid = (E/block_e, W/block_w); each step DMAs block_e
-    Bloom-row pairs into (block_e, block_w) VMEM slabs and reduces them in
-    one VPU pass. E must be a multiple of block_e and W of block_w.
-    """
-    n, w = bloom.shape
-    e = edges.shape[0]
-    block_w = min(block_w, w)
-    block_e = min(block_e, e)
-    grid = (pl.cdiv(e, block_e), pl.cdiv(w, block_w))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec((block_e,), lambda i, j, u, v: (i,)),
-        scratch_shapes=[
-            pltpu.VMEM((block_e, block_w), jnp.uint32),
-            pltpu.VMEM((block_e, block_w), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    kern = functools.partial(_edge_block_kernel, block_e=block_e,
-                             block_w=block_w)
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
-        interpret=interpret,
-    )(edges[:, 0], edges[:, 1], bloom)
-
-
-def _edge3_block_kernel(u_ref, v_ref, w_ref, bloom_ref, o_ref, a_buf, b_buf,
-                        c_buf, sems, *, block_e, block_w):
-    """3-slab variant of :func:`_edge_block_kernel` for (u, v, w) triples."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    _gather_rows((u_ref, v_ref, w_ref), i * block_e, bloom_ref,
-                 (a_buf, b_buf, c_buf), sems, count=block_e, block_w=block_w,
-                 j=j)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    cnt = jax.lax.population_count(a_buf[...] & b_buf[...] & c_buf[...])
-    o_ref[...] += jnp.sum(cnt.astype(jnp.int32), axis=1)
+    """uint32[n, W] sketch matrix + int32[E, 2] edges -> int32[E]."""
+    return fused_gather_popcount(bloom, (edges[:, 0], edges[:, 1]), _and,
+                                 block_e=block_e, block_w=block_w,
+                                 interpret=interpret)
 
 
 def _edge3_impl(bloom: jax.Array, triples: jax.Array, *,
-                block_e: int = 8, block_w: int = 512,
+                block_e: int = 256, block_w: int = 512,
                 interpret: bool = False) -> jax.Array:
-    """uint32[n, W] + int32[T, 3] triples -> int32[T] popcnt(Bu & Bv & Bw).
-
-    Same block-gather treatment as :func:`_edge_impl` with three slabs —
-    the 4-clique triple-intersection hot loop.
-    """
-    n, w = bloom.shape
-    t = triples.shape[0]
-    block_w = min(block_w, w)
-    block_e = min(block_e, t)
-    grid = (pl.cdiv(t, block_e), pl.cdiv(w, block_w))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec((block_e,), lambda i, j, u, v, w: (i,)),
-        scratch_shapes=[
-            pltpu.VMEM((block_e, block_w), jnp.uint32),
-            pltpu.VMEM((block_e, block_w), jnp.uint32),
-            pltpu.VMEM((block_e, block_w), jnp.uint32),
-            pltpu.SemaphoreType.DMA((3,)),
-        ],
-    )
-    kern = functools.partial(_edge3_block_kernel, block_e=block_e,
-                             block_w=block_w)
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.int32),
-        interpret=interpret,
-    )(triples[:, 0], triples[:, 1], triples[:, 2], bloom)
+    """uint32[n, W] + int32[T, 3] triples -> int32[T] popcnt(Bu & Bv & Bw)."""
+    return fused_gather_popcount(
+        bloom, (triples[:, 0], triples[:, 1], triples[:, 2]), _and,
+        block_e=block_e, block_w=block_w, interpret=interpret)
 
 
 # ----------------------------------------------------------------------------

@@ -6,9 +6,10 @@ per workload: each entrypoint builds the equivalent set expression and asks
 ``repro.engine.setexpr`` for the cached compiled form, which lowers to one
 fused VMEM pass (``repro.kernels.fused_expr``). On non-TPU backends the
 fused pass runs in Pallas interpret mode so correctness is validated
-everywhere; on TPU it compiles to Mosaic. Inputs are padded to pow2/block
-multiples inside the compiled object and the pad is sliced off, so callers
-never see blocking constraints.
+everywhere; on TPU it compiles to Mosaic (checked ahead of time for v5e by
+``tests/test_tpu_compile.py``). Inputs are padded to pow2/block multiples
+inside the compiled object and the pad is sliced off, so callers never see
+blocking constraints.
 
 Tuning knobs (``block_e``, ``block_w``, ``interpret``) are keyword-only.
 The former raw duplicates in ``bf_intersect.py`` (same names, unpadded
@@ -25,11 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from . import mh_intersect as _mh
-
-
-def _interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPU backends."""
-    return jax.default_backend() != "tpu"
+from .fused_expr import default_interpret
 
 
 def _pad_rows(x: jax.Array, mult: int, fill=0) -> jax.Array:
@@ -83,21 +80,21 @@ def bf_intersect3_pairs(a: jax.Array, b: jax.Array, c: jax.Array, *,
 
 
 def bf_edge_intersect(bloom: jax.Array, edges: jax.Array, *,
-                      block_e: int = 8, block_w: int = 512,
+                      block_e: int = 256, block_w: int = 512,
                       interpret: Optional[bool] = None) -> jax.Array:
-    """Block-gather AND+popcount over an edge list -> int32[E].
+    """Gathered AND+popcount over an edge list -> int32[E].
 
     Lowered as the compiled 2-way AND expression in gather form: edge
-    endpoints index sketch rows, one pipelined DMA burst per edge block.
+    endpoints index sketch rows, which feed one fused dense pass.
     """
     return _compiled_and(2, block_e=block_e, block_w=block_w,
                          interpret=interpret).ones(bloom, edges)
 
 
 def bf_edge_intersect3(bloom: jax.Array, triples: jax.Array, *,
-                       block_e: int = 8, block_w: int = 512,
+                       block_e: int = 256, block_w: int = 512,
                        interpret: Optional[bool] = None) -> jax.Array:
-    """3-way block-gather popcount over (u, v, w) triples (4-clique path)."""
+    """3-way gathered AND+popcount over (u, v, w) triples (4-clique path)."""
     return _compiled_and(3, block_e=block_e, block_w=block_w,
                          interpret=interpret).ones(bloom, triples)
 
@@ -111,7 +108,7 @@ def mh_intersect_pairs(a: jax.Array, b: jax.Array, sentinel: int, *,
     a2 = _pad_rows(a, be, fill=sentinel)
     b2 = _pad_rows(b, be, fill=sentinel)
     out = _mh.mh_intersect_pairs(a2, b2, sentinel, block_e=be,
-                                 interpret=_interpret())
+                                 interpret=default_interpret())
     return out[:e]
 
 
@@ -124,7 +121,7 @@ def khash_match_pairs(a: jax.Array, b: jax.Array, sentinel: int, *,
     a2 = _pad_rows(a, be, fill=sentinel)
     b2 = _pad_rows(b, be, fill=sentinel)
     out = _mh.khash_match_pairs(a2, b2, sentinel, block_e=be,
-                                interpret=_interpret())
+                                interpret=default_interpret())
     return out[:e]
 
 

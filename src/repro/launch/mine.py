@@ -1,121 +1,71 @@
-"""Distributed ProbGraph mining on the production mesh (shard_map).
+"""ProbGraph mining CLI: an engine session, or the edge-sharded TC fold.
 
-This is the paper's own workload at pod scale. Distribution plan:
+Two run modes, both through ``repro.engine``:
 
-  * sketch construction: vertices sharded over ('data',) — each shard hashes
-    its own CSR rows (embarrassingly parallel, paper Table V), then the
-    sketch matrix is all-gathered (it is s·|CSR| bytes ≈ small by design —
-    the whole point of the representation).
-  * mining (TC / clustering scores): edges sharded over ('data', 'model') —
-    every shard runs fixed-size AND+popcount over its edge slice and the
-    partial sums `psum` into the global count. Fixed-size sketches mean the
-    shards do identical work: no load imbalance, no stragglers from degree
-    skew (paper Fig. 1 panel 5 — this is the property that makes the method
-    SPMD-native).
+  * ``--algos tc,lcc,...``: a multi-query engine session over one shared
+    sketch build (:func:`mine_session`).
+  * no ``--algos``: the paper's own workload on a device mesh
+    (:func:`mine`). The sketch is built once and the TC fold runs with
+    ``EnginePlan.shard_edges``: edges are split over every mesh axis and
+    every shard runs fixed-size AND+popcount over its slice before a psum.
+    Fixed-size sketches mean the shards do identical work: no load
+    imbalance, no stragglers from degree skew (paper Fig. 1 panel 5).
 
-`--devices N` forces N host devices (set before jax import) so the same
-script demonstrates multi-device runs on CPU.
+``--devices N`` adds ``--xla_force_host_platform_device_count=N`` to
+``XLA_FLAGS`` when the module runs as a script, so the sharded fold can run
+on N host devices of the CPU backend; it changes nothing on an accelerator.
 """
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
 import time
 from typing import Optional
 
-# --devices must take effect before jax init
+# --devices must take effect before jax initializes its backends
 if __name__ == "__main__" and "--devices" in sys.argv:
-    n = sys.argv[sys.argv.index("--devices") + 1]
-    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+    _n = sys.argv[sys.argv.index("--devices") + 1]
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS", ""),
+        f"--xla_force_host_platform_device_count={_n}"]))
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh
 
-from repro.core import graph as G
-from repro.core import sketches as SK
-from repro.core import estimators as E
 from repro import engine as ENG
+from repro.compile_cache import use_compile_cache
+from repro.core import graph as G
+from repro.core import triangle_count
+from repro.distributed import sharding
 from repro.obs import metrics, trace
 
 
-def build_sketches_distributed(graph: G.Graph, mesh: Mesh, words: int,
-                               num_hashes: int, seed: int = 0) -> jax.Array:
-    """Vertex-sharded Bloom construction: shard_map over the 'data' axis."""
-    n = graph.n
-    total = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-    pad = (-n) % total
-    adj = jnp.pad(graph.adj, ((0, pad), (0, 0)), constant_values=n)
-    axes = P(mesh.axis_names)  # vertices over every mesh axis
+def mine(graph: G.Graph, mesh: Optional[Mesh] = None,
+         storage_budget: float = 0.25, num_hashes: int = 2, seed: int = 0):
+    """TC estimate with the engine's edge fold sharded over ``mesh``.
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(axes,),
-                       out_specs=axes)
-    def build(adj_shard):
-        total_bits = words * 32
-        pos, valid = SK._positions(adj_shard, n, num_hashes, total_bits, seed)
-        rows = adj_shard.shape[0]
-        row_idx = jnp.broadcast_to(jnp.arange(rows)[:, None, None], pos.shape)
-        bits = jnp.zeros((rows, total_bits), dtype=jnp.bool_)
-        bits = bits.at[row_idx.reshape(-1),
-                       jnp.where(jnp.broadcast_to(valid[..., None], pos.shape),
-                                 pos, 0).reshape(-1)].max(
-            jnp.broadcast_to(valid[..., None], pos.shape).reshape(-1))
-        return SK.pack_bits(bits)
-
-    return build(adj)[:n]
-
-
-def triangle_count_distributed(graph: G.Graph, bloom: jax.Array, mesh: Mesh,
-                               num_hashes: int) -> jax.Array:
-    """Edge-sharded TC_AND: psum of per-shard estimator sums / 3."""
-    m = graph.m
-    total = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-    pad = (-m) % total
-    edges = jnp.concatenate(
-        [graph.edges, jnp.zeros((pad, 2), graph.edges.dtype)], axis=0)
-    mask = jnp.concatenate([jnp.ones(m, bool), jnp.zeros(pad, bool)])
-    total_bits = bloom.shape[1] * 32
-    eaxes = P(mesh.axis_names)
-
-    @functools.partial(
-        shard_map, mesh=mesh,
-        in_specs=(eaxes, P(None, None), eaxes),
-        out_specs=P())
-    def tc_shard(edge_shard, bloom_rep, mask_shard):
-        ru = jnp.take(bloom_rep, edge_shard[:, 0], axis=0)
-        rv = jnp.take(bloom_rep, edge_shard[:, 1], axis=0)
-        ones = jnp.sum(jax.lax.population_count(ru & rv), axis=-1)
-        est = E.bf_intersection_and_from_ones(ones, total_bits, num_hashes)
-        local = jnp.sum(jnp.where(mask_shard, est, 0.0))
-        for ax in mesh.axis_names:
-            local = jax.lax.psum(local, ax)
-        return local
-
-    return tc_shard(edges, bloom, mask) / 3.0
-
-
-def mine(graph: G.Graph, mesh: Optional[Mesh] = None, storage_budget: float = 0.25,
-         num_hashes: int = 2, seed: int = 0):
-    """End-to-end distributed TC estimate; falls back to single-device mesh."""
+    ``mesh`` defaults to every device on one "data" axis. Returns the
+    estimate, the sketch build and fold seconds, the Bloom words per
+    vertex and the device count.
+    """
     if mesh is None:
-        ndev = len(jax.devices())
-        mesh = jax.make_mesh((ndev,), ("data",))
-    words = SK.bloom_words_for_budget(graph.n, graph.m, storage_budget)
+        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
     t0 = time.perf_counter()
-    bloom = build_sketches_distributed(graph, mesh, words, num_hashes, seed)
-    bloom.block_until_ready()
+    sess = ENG.session(graph, "bf", storage_budget=storage_budget,
+                       num_hashes=num_hashes, seed=seed, shard_edges=True)
+    jax.block_until_ready(sess.sketch.data)
     t_build = time.perf_counter() - t0
     t0 = time.perf_counter()
-    tc = triangle_count_distributed(graph, bloom, mesh, num_hashes)
-    tc = float(tc)
+    with sharding.use_rules(mesh):
+        tc = float(triangle_count(graph, sess.sketch, plan=sess.plan))
     t_mine = time.perf_counter() - t0
     return {"tc_estimate": tc, "build_s": t_build, "mine_s": t_mine,
-            "words": words, "devices": int(np.prod(list(mesh.shape.values())))}
+            "words": int(sess.sketch.data.shape[1]),
+            "devices": int(mesh.devices.size)}
 
 
 def mine_session(graph: G.Graph, algos: list[str], storage_budget: float = 0.25,
@@ -158,7 +108,9 @@ def mine_session(graph: G.Graph, algos: list[str], storage_budget: float = 0.25,
     return results
 
 
-def main():
+def main(argv=None):
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``); returns what it
+    printed last as a dict."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=1)
     ap.add_argument("--scale", type=int, default=12, help="Kronecker scale")
@@ -171,15 +123,16 @@ def main():
                          "multi-query engine session over one shared sketch "
                          "build")
     ap.add_argument("--use-kernel", action="store_true",
-                    help="route BF popcounts through the Pallas block-gather "
-                         "kernels (TPU; interpret elsewhere)")
+                    help="route BF popcounts through the fused Pallas "
+                         "pass (Mosaic on TPU; interpret elsewhere)")
     ap.add_argument("--trace", default=None, metavar="OUT_JSON",
                     help="record spans and write a Chrome-trace/Perfetto "
                          "JSON of the run to this path")
     ap.add_argument("--metrics", action="store_true",
                     help="print a metric-registry snapshot JSON line")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    use_compile_cache()
     if args.trace:
         trace.enable()
         trace.clear()
@@ -194,19 +147,18 @@ def main():
         for name, (val, secs) in res.items():
             print(f"  {name:8s} = {val:<12.4g} ({secs:.2f}s)")
         # machine-readable twin of the human output (one JSON line)
-        print(json.dumps({
+        out = {
             "event": "mine_session", "n": g.n, "m": g.m, "d_max": g.d_max,
             "budget": args.budget, "use_kernel": args.use_kernel,
             "sketch_bytes": sketch_bytes, "build_s": build_s,
             "algos": {name: {"value": val, "seconds": secs}
                       for name, (val, secs) in res.items()},
-        }))
+        }
+        print(json.dumps(out))
         _emit_obs(args)
-        return
+        return out
 
-    ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev,), ("data",))
-    out = mine(g, mesh, storage_budget=args.budget)
+    out = mine(g, storage_budget=args.budget)
     print(f"TC_AND={out['tc_estimate']:.0f}  build={out['build_s']:.2f}s "
           f"mine={out['mine_s']:.2f}s devices={out['devices']}")
     if args.exact:
@@ -216,6 +168,7 @@ def main():
         print(f"TC_exact={tc} ({time.perf_counter()-t0:.2f}s) "
               f"rel_err={abs(out['tc_estimate']-tc)/max(tc,1):.3f}")
     _emit_obs(args)
+    return out
 
 
 def _emit_obs(args):

@@ -17,7 +17,8 @@ default strict policy).
   PYTHONPATH=src python -m repro.launch.stream --scale 10 --batches 12 --verify
   PYTHONPATH=src python -m repro.launch.stream --checkpoint-dir /tmp/ck --restore
 
-The last line printed is a machine-readable JSON summary.
+The last line printed is a machine-readable JSON summary. With
+``--verify`` the run exits nonzero unless every batch matched exactly.
 """
 from __future__ import annotations
 
@@ -28,6 +29,7 @@ import time
 import numpy as np
 
 from repro import engine as ENG
+from repro.compile_cache import use_compile_cache
 from repro.core import graph as G
 from repro.core import sketches as SK
 from repro.obs import metrics, trace
@@ -47,8 +49,14 @@ def build_stream(scale: int, edge_factor: int, stream_frac: float, seed: int):
 
 def verify_against_static(st: StreamSession, pairs: np.ndarray,
                           lc_seed: int | None = None) -> dict:
-    """From-scratch engine.session on the equivalent static graph."""
-    gs = G.from_edge_array(st.dyn.n, st.dyn.edge_array())
+    """From-scratch engine.session on the equivalent static graph.
+
+    The static adjacency is padded to the stream's row capacity: padding
+    changes no answer, and every batch's static build then reuses the
+    programs compiled for the stream's own adjacency shape.
+    """
+    gs = G.from_edge_array(st.dyn.n, st.dyn.edge_array(),
+                           pad_to_max_degree=st.dyn.capacity)
     mt = st.maintainer
     sk = None
     if mt is not None:
@@ -80,7 +88,9 @@ def verify_against_static(st: StreamSession, pairs: np.ndarray,
     return out
 
 
-def main():
+def main(argv=None):
+    """Run the replay on ``argv`` (default ``sys.argv[1:]``); returns the
+    JSON summary it printed last."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", type=int, default=10, help="Kronecker scale")
     ap.add_argument("--edge-factor", type=int, default=8)
@@ -113,8 +123,9 @@ def main():
                          "JSON of the replay to this path")
     ap.add_argument("--metrics", action="store_true",
                     help="embed metric-registry snapshots in the summary")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    use_compile_cache()
     if args.trace:
         trace.enable()
         trace.clear()
@@ -220,6 +231,10 @@ def main():
         trace.disable()
         summary["trace"] = args.trace
     print(json.dumps(summary))
+    if args.verify and summary["verify_all_exact"] is False:
+        raise SystemExit("stream replay: answers differ from the static "
+                         "session (verify_all_exact is false)")
+    return summary
 
 
 if __name__ == "__main__":

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as SH
@@ -75,10 +74,10 @@ def moe_fwd_ep(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         sh_args = (z, z, z)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(x_spec, router_spec, wi_spec, wi_spec, wo_spec,
                   sh_in_spec, sh_in_spec, sh_out_spec),
-        out_specs=x_spec, check_rep=False)
+        out_specs=x_spec, check_vma=False)
     def ep_block(x_loc, router, wi_gate, wi_up, wo, sh_gate, sh_up, sh_wo):
         if "data" in mesh.shape and mesh.shape["data"] > 1:
             wi_gate = lax.all_gather(wi_gate, "data", axis=1, tiled=True)

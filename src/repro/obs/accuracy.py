@@ -34,8 +34,10 @@ def fill_ratio(sketch) -> float:
     """
     data = np.asarray(sketch.data)
     if sketch.kind == "bf":
-        # uint32 words -> mean bit density
-        bits = np.unpackbits(data.view(np.uint8), axis=-1)
+        # uint32 words -> mean bit density; a TPU array can arrive with
+        # padded row strides, which a dtype view of the word axis refuses
+        bits = np.unpackbits(np.ascontiguousarray(data).view(np.uint8),
+                             axis=-1)
         return float(bits.mean())
     if sketch.kind in ("kh", "1h"):
         return float((data < sketch.n).mean())

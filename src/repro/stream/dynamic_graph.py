@@ -110,6 +110,7 @@ class TrafficMeter:
         self._total = self.registry.counter("traffic_bytes", path="delta")
         self._last = self.registry.gauge("traffic_bytes_last_delta")
         self._steps = self.registry.counter("traffic_steps")
+        self._donated = self.registry.counter("device_updates_donated")
 
     @property
     def bytes_init(self) -> int:
@@ -130,6 +131,15 @@ class TrafficMeter:
     def steps(self) -> int:
         """Committed delta/flush traffic steps."""
         return self._steps.value
+
+    @property
+    def donated(self) -> int:
+        """Device updates that donated the published buffers."""
+        return self._donated.value
+
+    def count_donation(self):
+        """Count one device update that donated its input buffers."""
+        self._donated.inc()
 
     def begin_delta(self):
         """Reset the per-delta byte counter (called at each delta's start)."""
@@ -158,6 +168,7 @@ class TrafficMeter:
             "bytes_last_delta": self.bytes_delta,
             "bytes_per_delta_mean": self.bytes_total / max(self.steps, 1),
             "steps": self.steps,
+            "donated_updates": self.donated,
         }
 
 
@@ -196,24 +207,20 @@ def _splice_edges_impl(edges, del_pos, ins_pos, ins_uv, m_old, n):
 
 
 @functools.lru_cache(maxsize=None)
-def _update_fns(donate: bool = True):
-    """The jitted device-update kernels, donation decided at first *use*.
+def _update_fns(donate: bool):
+    """The jitted device-update kernels, donating their first argument
+    when ``donate`` is set.
 
-    Donating the old buffer gives true in-place device updates; CPU has no
-    donation support and would warn on every compile. The backend query must
-    not run at import time — it would initialize JAX as an import side
-    effect and freeze the decision before the program configures platforms
-    (same call-time pattern as ``repro.kernels.ops``).
-
-    ``donate=False`` selects non-donating variants even off-CPU: when an
-    in-flight flush still reads the published buffers, donating them to
-    build the next version would invalidate arrays under it.
+    Donating the old buffer gives true in-place device updates.
+    ``donate=False`` selects non-donating variants whenever an in-flight
+    flush still reads the published buffers, since donating them to build
+    the next version would invalidate arrays under it.
     :meth:`DynamicGraph.donate_ok` makes the per-delta call — a session's
     lease-aware policy when one is installed (donation re-engages whenever
     no stale view is in flight and no read lease is out), else the
     conservative any-live-snapshot veto.
     """
-    argnums = (0,) if donate and jax.default_backend() != "cpu" else ()
+    argnums = (0,) if donate else ()
     return tuple(jax.jit(fn, donate_argnums=argnums) for fn in
                  (_scatter_rows_impl, _scatter_vals_impl, _splice_edges_impl))
 
@@ -308,10 +315,11 @@ class DeviceGraphState:
         """The untraced body of :meth:`apply_delta` — shadow build + swap."""
         # donation consumes the input buffer, which is exactly the published
         # generation an in-flight reader may still be using: only donate
-        # when the graph's donation policy proves nothing does (CPU never
-        # donates)
-        _scatter_rows, _scatter_vals, _splice_edges = \
-            _update_fns(dyn.donate_ok())
+        # when the graph's donation policy proves nothing does
+        donate = dyn.donate_ok()
+        if donate:
+            self.meter.count_donation()
+        _scatter_rows, _scatter_vals, _splice_edges = _update_fns(donate)
         n = self.n
         deg, adj, edges, e_cap = (self._buf.deg, self._buf.adj,
                                   self._buf.edges, self._buf.e_cap)

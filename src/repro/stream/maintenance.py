@@ -35,7 +35,7 @@ import numpy as np
 from ..core import bounds
 from ..core.hashing import hash_u32, hash_unit_interval
 from ..core.sketches import (KMV_PAD, PAD_HASH, SketchSet, _map_vertex_chunks,
-                             _positions, bloom_rows, bloom_words_for_budget,
+                             bloom_bits, bloom_rows, bloom_words_for_budget,
                              khash_rows, kmv_rows, minhash_k_for_budget,
                              onehash_rows, onehash_values, pack_bits)
 from ..engine.api import pow2_bucket
@@ -93,13 +93,7 @@ STRICT_POLICY = ErrorBudgetPolicy(rel_tolerance=0.0)
                                              "total_bits"))
 def _bloom_insert(data, rows, new_elems, *, n, num_hashes, seed, total_bits):
     """Scatter-OR only the new elements' bit positions into the given rows."""
-    pos, valid = _positions(new_elems, n, num_hashes, total_bits, seed)
-    t = rows.shape[0]
-    row_idx = jnp.broadcast_to(jnp.arange(t)[:, None, None], pos.shape)
-    vmask = jnp.broadcast_to(valid[..., None], pos.shape)
-    bits = jnp.zeros((t, total_bits), dtype=jnp.bool_)
-    bits = bits.at[row_idx.reshape(-1),
-                   jnp.where(vmask, pos, 0).reshape(-1)].max(vmask.reshape(-1))
+    bits = bloom_bits(new_elems, n, num_hashes, total_bits, seed)
     cur = jnp.take(data, rows, axis=0)
     # padded entries carry row index n (out of range) and are dropped
     return data.at[rows].set(cur | pack_bits(bits), mode="drop")

@@ -1,4 +1,4 @@
-"""Batched mining engine: block-gather kernels, plan routing, sessions."""
+"""Batched mining engine: fused-pass kernels, plan routing, sessions."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -24,7 +24,7 @@ def sk(g):
 
 
 # ---------------------------------------------------------------------------
-# block-gather kernels vs the reference popcount path (interpret mode)
+# fused-pass kernels vs the reference popcount path (interpret mode)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("block_e", [1, 8, 64])

@@ -172,13 +172,18 @@ def test_overflow_spills_to_dense(kron):
 def test_auto_mode_selects_by_cap_vs_n(kron):
     # tight eps on a small graph: the ACL cap rivals n, auto must go dense
     assert LC.resolve_frontier_mode(
-        ENG.EnginePlan(), kron.n, ALPHA, 1e-4) == "dense"
+        ENG.EnginePlan(), kron.n, kron.d_max, ALPHA, 1e-4) == "dense"
     # loose eps on a big n: cap is far below n, auto must go sparse
     assert LC.resolve_frontier_mode(
-        ENG.EnginePlan(), 1 << 20, ALPHA, 3e-2) == "sparse"
+        ENG.EnginePlan(), 1 << 20, 64, ALPHA, 3e-2) == "sparse"
+    # ... unless the rows each slot gathers make a sparse round as large as
+    # a dense one (Graph500 scale 16: n = 65,536, d_max = 9,729, cap 8,192)
+    assert LC.resolve_frontier_mode(
+        ENG.EnginePlan(), 1 << 16, 9729, ALPHA, 1e-3) == "dense"
     with pytest.raises(ValueError):
         LC.resolve_frontier_mode(
-            ENG.EnginePlan(frontier_mode="bogus"), kron.n, ALPHA, 1e-2)
+            ENG.EnginePlan(frontier_mode="bogus"), kron.n, kron.d_max,
+            ALPHA, 1e-2)
     res = LC.local_cluster(kron, np.array([3], np.int32), ALPHA, 1e-4)
     assert res.frontier is None and not res.spilled   # auto stayed dense
 

@@ -251,6 +251,15 @@ def test_fill_ratio_in_unit_interval(kind):
     assert reg.value("sketch_fill_ratio", kind=kind) == r
 
 
+def test_bf_fill_ratio_of_strided_rows():
+    """Rows with padded strides (as a TPU transfer can give) still count."""
+    words = np.zeros((4, 8), np.uint32)
+    words[:, 0] = 0xFF                                # 8 of 192 bits per row
+    sk = SK.SketchSet(data=words[:, :6], kind="bf", num_hashes=2, k=0,
+                      seed=0, n=4)
+    assert accuracy.fill_ratio(sk) == 8 / 192
+
+
 @pytest.mark.parametrize("kind", ["bf", "kh"])
 def test_record_pair_error_gauges(kind):
     g = G.kronecker(7, 8, seed=0)
@@ -294,19 +303,22 @@ def test_traffic_meter_is_a_registry_view():
     tm.begin_delta()
     tm.put(np.zeros(10, np.int32))                   # 40 bytes delta
     tm.put(np.zeros(5, np.int32))                    # +20
+    tm.count_donation()
     tm.commit_step()
     assert tm.bytes_init == 400
     assert tm.bytes_delta == 60
     assert tm.bytes_total == 60
     assert tm.steps == 1
+    assert tm.donated == 1
     assert tm.stats() == {"bytes_init": 400, "bytes_total": 60,
                           "bytes_last_delta": 60, "bytes_per_delta_mean": 60.0,
-                          "steps": 1}
+                          "steps": 1, "donated_updates": 1}
     # the same numbers, straight from the backing registry
     assert tm.registry.value("traffic_bytes", path="init") == 400
     assert tm.registry.value("traffic_bytes", path="delta") == 60
     assert tm.registry.value("traffic_bytes_last_delta") == 60
     assert tm.registry.value("traffic_steps") == 1
+    assert tm.registry.value("device_updates_donated") == 1
     tm.begin_delta()
     assert tm.bytes_delta == 0 and tm.bytes_total == 60
     # meters do not share registries (concurrent sessions stay isolated)

@@ -54,8 +54,8 @@ def bloom(rng):
                                                (3, 1, 512), (21, 8, 4),
                                                (64, 64, 512)])
 def test_compiled_and2_gather_matches_legacy(bloom, rng, t, block_e, block_w):
-    """Gather-form 2-way AND == the pre-PR block-gather kernel, bit for bit,
-    on ragged tuple counts and ragged word axes."""
+    """Gather-form 2-way AND == the private fixed-arity gather form, bit for
+    bit, on ragged tuple counts and ragged word axes."""
     n, w = bloom.shape
     edges = rng.integers(0, n, size=(t, 2), dtype=np.int32)
     u, v = setexpr.rows(2)

@@ -1,0 +1,119 @@
+"""Ahead-of-time compiles of the Bloom main path for a TPU v5e.
+
+Interpret mode runs a Pallas kernel body in Python and accepts block shapes
+and DMAs that the chip's compiler refuses, and the CPU accepts layouts that
+need more than a chip's memory. These tests compile the fused popcount
+pass, the Bloom build and the jnp TC fold for a described (not attached)
+``v5e:2x2`` at the shapes of
+the Graph500 Kronecker graph at scale 16, edge factor 16 (n = 65,536,
+m = 910,200, d_max = 9,729), whose Bloom rows are W = 8, 116 and 462 words
+at storage budgets 0.25, 4 and 16. Nothing runs: a compile that passes says
+the chip's compiler accepts the program, not that its results are right.
+
+The topology is described inside a module fixture, never at import, so every
+test worker collects the same tests and only the one given this file loads
+the TPU compiler. Where no topology can be described, the tests skip.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import engine as ENG
+from repro.core import triangle_count
+from repro.core.graph import Graph
+from repro.core.sketches import SketchSet, build_bloom
+from repro.engine import setexpr
+
+N, M, D_MAX = 65_536, 910_200, 9_729        # Graph500 scale 16, edge factor 16
+WIDTHS = (8, 116, 462)                       # budgets 0.25, 4, 16 at scale 16
+TUPLES = 1 << 16
+SWEEP_ROWS = 8 * 512                         # 8 seeds x sweep_cap 512
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, with the persistent cache off (a
+    compile for a described chip is written to it but cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _mosaic_hlo(fn, *args) -> str:
+    """Compile ``fn`` for ``args`` and return the optimized HLO text."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("k", [2, 3, 4])
+@pytest.mark.parametrize("w", WIDTHS)
+def test_fused_gather_compiles_for_v5e(one_chip, w, k):
+    """The k-way AND in gather form, as the engine's kernel path runs it."""
+    ce = setexpr.compile_expr(setexpr.and_all(*setexpr.rows(k)),
+                              use_kernel=True, interpret=False)
+    hlo = _mosaic_hlo(ce.ones, _sds((N, w), jnp.uint32, one_chip),
+                      _sds((TUPLES, k), jnp.int32, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("w", WIDTHS)
+def test_fused_rows_sweep_cut_compiles_for_v5e(one_chip, w):
+    """The dense form the sweep cut feeds with its computed prefix rows."""
+    u, v = setexpr.rows(2)
+    ce = setexpr.compile_expr(u & v, use_kernel=True, interpret=False)
+    row = _sds((SWEEP_ROWS, w), jnp.uint32, one_chip)
+    assert "tpu_custom_call" in _mosaic_hlo(ce.ones_rows, row, row)
+
+
+def _graph(sharding) -> Graph:
+    """The scale-16 graph's shapes, placed on ``sharding``."""
+    i32 = jnp.int32
+    return Graph(indptr=_sds((N + 1,), i32, sharding),
+                 indices=_sds((2 * M,), i32, sharding),
+                 adj=_sds((N, D_MAX), i32, sharding),
+                 deg=_sds((N,), i32, sharding),
+                 edges=_sds((M, 2), i32, sharding),
+                 n_vertices=N, n_edges=M, d_max=D_MAX)
+
+
+def test_bloom_build_fits_v5e(one_chip):
+    """The device Bloom build over the padded scale-16 adjacency at the
+    widest budget stays well inside a 16 GB chip (a [rows, d_max, b]
+    position layout once asked for 20 GB)."""
+    compiled = jax.jit(lambda g: build_bloom(g, WIDTHS[-1])).lower(
+        _graph(one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_jnp_tc_fold_compiles_for_v5e(one_chip):
+    """The whole jnp TC fold over all scale-16 edges at budget 4 fits."""
+    graph = _graph(one_chip)
+    sketch = SketchSet(data=_sds((N, WIDTHS[1]), jnp.uint32, one_chip),
+                       kind="bf", num_hashes=2, k=0, seed=0, n=N)
+    plan = ENG.plan_for(graph, sketch)
+    compiled = jax.jit(lambda g, s: triangle_count(g, s, plan=plan)).lower(
+        graph, sketch).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
