@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import engine as eng
-from repro.analysis import live
 from repro.core import graph as G, sketches as S
 from repro.core import triangle_count, pair_similarity
 from repro.core.algorithms.tc import local_clustering_coefficient
@@ -49,18 +48,13 @@ def run(scale: int = 12, budget: float = 1.0):
     emit(f"engine_session_tc_lcc_sim_s{scale}", us_sess,
          f"independent_us={us_indep:.1f};amortization={us_indep / us_sess:.2f}x")
 
-    # degree-ordered vs natural edge layout for the fold (jnp path); each
-    # compiled fold also reports its achieved fraction of the HLO-cost
-    # roofline bound (recorded as a gauge in the global metrics registry)
+    # degree-ordered vs natural edge layout for the fold (jnp path)
     for order in (False, True):
         plan = eng.EnginePlan(edge_chunk=16384, degree_order=order)
         fn = jax.jit(lambda: eng.sum_edge_cardinalities(g, sk, plan)
                      ).lower().compile()
         us = timeit(lambda: fn(), iters=3)
-        rf = live.record_roofline(f"engine_fold_order{int(order)}", fn,
-                                  us * 1e-6)
-        emit(f"engine_fold_s{scale}_order{int(order)}", us,
-             f"edges={g.m};roofline_frac={rf['fraction']:.3g}")
+        emit(f"engine_fold_s{scale}_order{int(order)}", us, f"edges={g.m}")
 
     # one-shot session wall time including sketch build (serving cold start)
     t0 = time.perf_counter()

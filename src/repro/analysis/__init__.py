@@ -1,3 +1,3 @@
-from . import hlo, live, roofline
+from . import hlo, roofline
 
-__all__ = ["hlo", "live", "roofline"]
+__all__ = ["hlo", "roofline"]

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ... import engine as eng
+from ...obs import trace
 from ..graph import Graph
 from ..sketches import SketchSet
 
@@ -65,11 +66,13 @@ def jarvis_patrick(graph: Graph, sketch: Optional[SketchSet] = None,
     if edge_cards is None:
         plan = eng.resolve_plan(plan, graph, sketch, kw)
         edge_cards = eng.edge_cardinalities(graph, sketch, plan)
-    du = jnp.take(graph.deg, edges[:, 0]).astype(jnp.float32)
-    dv = jnp.take(graph.deg, edges[:, 1]).astype(jnp.float32)
-    score = similarity_from_cardinalities(edge_cards, du, dv, similarity)
-    keep = score >= threshold
-    labels = _connected_components(graph.n, edges, keep)
+    with trace.span("jp.similarity"):
+        du = jnp.take(graph.deg, edges[:, 0]).astype(jnp.float32)
+        dv = jnp.take(graph.deg, edges[:, 1]).astype(jnp.float32)
+        score = similarity_from_cardinalities(edge_cards, du, dv, similarity)
+        keep = score >= threshold
+    with trace.span("jp.label_propagation"):
+        labels = _connected_components(graph.n, edges, keep)
     # count distinct labels among non-isolated semantics: every vertex is its
     # own cluster when no kept edge touches it (paper counts all clusters)
     num = jnp.sum(labels == jnp.arange(graph.n, dtype=jnp.int32))

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import trace
 from .graph import Graph
 from .hashing import hash_u32, hash_unit_interval, np_hash_u32
 
@@ -281,8 +282,10 @@ def build(graph: Graph, kind: str, storage_budget: float = 0.25,
     """Paper Listing 6 entry point: ProbGraph(g, KIND, s)."""
     if kind == "bf":
         w = words if words is not None else bloom_words_for_budget(graph.n, graph.m, storage_budget)
-        return SketchSet(data=build_bloom(graph, w, num_hashes, seed), kind="bf",
-                         num_hashes=num_hashes, k=0, seed=seed, n=graph.n)
+        with trace.span("sketch.bloom_build", words=w):
+            data = build_bloom(graph, w, num_hashes, seed)
+        return SketchSet(data=data, kind="bf", num_hashes=num_hashes, k=0,
+                         seed=seed, n=graph.n)
     kk = k if k is not None else minhash_k_for_budget(graph.n, graph.m, storage_budget)
     if kind in ("kh", "1h", "kmv"):
         builder = {"kh": build_khash, "1h": build_1hash, "kmv": build_kmv}[kind]

@@ -382,22 +382,25 @@ class MiningSession:
 
     def triangle_count(self) -> jax.Array:
         """Scalar TC estimate from the shared per-edge cardinality pass."""
-        return jnp.sum(self.edge_cardinalities()) / 3.0
+        with trace.span("engine.triangle_count"):
+            return jnp.sum(self.edge_cardinalities()) / 3.0
 
     def local_clustering(self) -> jax.Array:
         """Per-vertex clustering coefficients float32[n] (shared pass)."""
         from ..core.algorithms.tc import local_clustering_coefficient
-        return local_clustering_coefficient(
-            self.graph, self.sketch, plan=self.plan,
-            edge_cards=self.edge_cardinalities())
+        with trace.span("engine.local_clustering"):
+            return local_clustering_coefficient(
+                self.graph, self.sketch, plan=self.plan,
+                edge_cards=self.edge_cardinalities())
 
     def jarvis_patrick(self, similarity: str = "common",
                        threshold: float = 2.0):
         """Jarvis–Patrick clustering ``(labels int32[n], num_clusters)``."""
         from ..core.algorithms.clustering import jarvis_patrick
-        return jarvis_patrick(self.graph, self.sketch, similarity, threshold,
-                              plan=self.plan,
-                              edge_cards=self.edge_cardinalities())
+        with trace.span("engine.jarvis_patrick", similarity=similarity):
+            return jarvis_patrick(self.graph, self.sketch, similarity,
+                                  threshold, plan=self.plan,
+                                  edge_cards=self.edge_cardinalities())
 
     def four_clique_count(self, **kw) -> jax.Array:
         """Scalar 4-clique count estimate (3-way sketch intersections)."""
@@ -553,8 +556,9 @@ def session(graph: Graph, sketch: Optional[SketchSet] | str = "bf",
     ``sketch`` may be a prebuilt SketchSet, a kind string ("bf" | "kh" |
     "1h" | "kmv") to build here, or None for the exact baseline.
     """
-    if isinstance(sketch, str):
-        sketch = build_sketch(graph, sketch, storage_budget,
-                              num_hashes=num_hashes, seed=seed)
-    return MiningSession(graph, sketch, resolve_plan(plan, graph, sketch,
-                                                     plan_kw))
+    with trace.span("engine.session"):
+        if isinstance(sketch, str):
+            sketch = build_sketch(graph, sketch, storage_budget,
+                                  num_hashes=num_hashes, seed=seed)
+        return MiningSession(graph, sketch, resolve_plan(plan, graph, sketch,
+                                                         plan_kw))

@@ -19,16 +19,48 @@ Export is the Chrome trace-event JSON format (``ph: "X"`` complete events,
 microsecond timestamps), which loads directly in Perfetto / chrome://tracing;
 ``aggregate()`` gives per-span-name count/total wall time for benchmark
 breakdowns.
+
+On the profiler's clock: while enabled, every span also enters a
+``jax.profiler.TraceAnnotation`` of its bare name (attributes stay in the
+ring buffer), so a ``jax.profiler`` trace holds the program's spans on its
+host plane. The ring buffer reads the same clock as that plane,
+``CLOCK_REALTIME`` in nanoseconds (``time.time_ns()``; each event's
+``start_ns``), so a span's two records agree to microseconds.
+
+Compile steps: while enabled, ``jax.monitoring`` listeners record JAX's own
+compile-step events (:data:`COMPILE_STEPS`) into the metrics registry as
+``compile_step_s`` (seconds, exclusive of nested compile steps) and
+``compile_step_total`` (events), both labelled ``step=`` and ``span=`` (the
+innermost open program span), and into the ring buffer as ``jax.<step>``
+spans whose parent is that span.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import json
 import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+from .metrics import REGISTRY
+
+#: JAX's compile-step monitoring events and the step name each records as.
+#: The first three arrive with a start and an end on ``time.time()``; the
+#: persistent-cache load arrives as a duration only, when it ends (inside
+#: its ``backend_compile``).
+COMPILE_STEPS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir_module",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+#: the ``span=`` label of a compile step that no program span encloses
+OUTSIDE = "outside_program_spans"
 
 
 class _NullSpan:
@@ -58,7 +90,7 @@ class _Span:
     """One live span: records name/attrs/parent and times its ``with`` body."""
 
     __slots__ = ("_tracer", "name", "attrs", "_fenced", "_t0", "_parent",
-                 "_depth")
+                 "_depth", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -82,7 +114,9 @@ class _Span:
         self._parent = stack[-1].name if stack else None
         self._depth = len(stack)
         stack.append(self)
-        self._t0 = time.perf_counter()
+        self._annotation = self._tracer._annotation(self.name)
+        self._annotation.__enter__()
+        self._t0 = time.time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -93,7 +127,8 @@ class _Span:
             except Exception:  # noqa: BLE001 - tracers/aborted buffers
                 pass
             self._fenced = None
-        t1 = time.perf_counter()
+        t1 = time.time_ns()
+        self._annotation.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -118,7 +153,9 @@ class Tracer:
             maxlen=self.capacity)
         self._lock = threading.Lock()
         self._local = threading.local()
-        self._origin = time.perf_counter()
+        self._origin = time.time_ns()
+        self._annotation = None    # jax.profiler.TraceAnnotation once enabled
+        self._listeners = (self._on_time_span, self._on_duration)
         self.recorded = 0          # total spans ever recorded (ring may drop)
 
     # -- hot path -----------------------------------------------------------
@@ -135,13 +172,14 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
-    def _record(self, name: str, t0: float, t1: float,
+    def _record(self, name: str, t0: int, t1: int,
                 parent: Optional[str], depth: int, attrs: dict,
                 error: bool = False) -> None:
         event = {
             "name": name,
-            "ts": (t0 - self._origin) * 1e6,      # µs since tracer origin
-            "dur": (t1 - t0) * 1e6,
+            "ts": (t0 - self._origin) * 1e-3,     # µs since tracer origin
+            "dur": (t1 - t0) * 1e-3,
+            "start_ns": t0,                       # the profiler's clock
             "tid": threading.get_ident(),
             "parent": parent,
             "depth": depth,
@@ -153,19 +191,80 @@ class Tracer:
             self._events.append(event)
             self.recorded += 1
 
+    # -- compile steps (jax.monitoring listeners, installed while enabled) ---
+
+    def _on_time_span(self, event: str, start: float, end: float, **kw):
+        step = COMPILE_STEPS.get(event)
+        if step is not None and event != _CACHE_RETRIEVAL:
+            self._compile_step(step, int(start * 1e9), int(end * 1e9),
+                               kw.get("fun_name"))
+
+    def _on_duration(self, event: str, duration: float, **kw):
+        if event == _CACHE_RETRIEVAL:
+            t1 = time.time_ns()
+            self._compile_step(COMPILE_STEPS[event],
+                               t1 - int(duration * 1e9), t1, None)
+
+    def _compile_step(self, step: str, t0: int, t1: int,
+                      fun_name: Optional[str]) -> None:
+        """Count one compile step against the innermost open span.
+
+        Steps nest (a jitted function traced inside another's trace, a
+        cache load inside its backend compile) and are reported as they
+        end, inner first; the seconds counted are the step's own, less the
+        steps reported inside it, so the counters sum to host time.
+        """
+        stack = self._stack()
+        parent = stack[-1].name if stack else None
+        done = getattr(self._local, "steps_done", None)
+        if done is None:
+            done = self._local.steps_done = []
+        inner = 0
+        while done and done[-1][0] >= t0:
+            a, b = done.pop()
+            inner += b - a
+        done.append((t0, t1))
+        del done[:-256]          # top-level steps pile up; keep the recent
+        label = parent or OUTSIDE
+        REGISTRY.gauge("compile_step_s", step=step, span=label).add(
+            (t1 - t0 - inner) * 1e-9)
+        REGISTRY.counter("compile_step_total", step=step, span=label).inc()
+        self._record("jax." + step, t0, t1, parent, len(stack),
+                     {"fun_name": fun_name} if fun_name else {})
+
     # -- control ------------------------------------------------------------
 
     def enable(self, capacity: Optional[int] = None) -> None:
-        """Turn tracing on (optionally resizing the ring buffer)."""
+        """Turn tracing on (optionally resizing the ring buffer); spans then
+        also annotate profiler traces, and the compile-step listeners are
+        installed."""
         if capacity is not None and int(capacity) != self.capacity:
             self.capacity = int(capacity)
             with self._lock:
                 self._events = collections.deque(self._events,
                                                  maxlen=self.capacity)
+        if not self.enabled:
+            import jax
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
+            on_span, on_duration = self._listeners
+            jax.monitoring.register_event_time_span_listener(on_span)
+            jax.monitoring.register_event_duration_secs_listener(on_duration)
         self.enabled = True
 
     def disable(self) -> None:
-        """Turn tracing off (recorded spans are kept until ``clear``)."""
+        """Turn tracing off and remove the compile-step listeners (recorded
+        spans are kept until ``clear``)."""
+        if self.enabled:
+            import jax
+
+            on_span, on_duration = self._listeners
+            # already gone if someone cleared every jax.monitoring listener
+            with contextlib.suppress(AssertionError, ValueError):
+                jax.monitoring.unregister_event_time_span_listener(on_span)
+            with contextlib.suppress(AssertionError, ValueError):
+                jax.monitoring.unregister_event_duration_listener(on_duration)
         self.enabled = False
 
     def clear(self) -> None:
@@ -173,7 +272,7 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self.recorded = 0
-            self._origin = time.perf_counter()
+            self._origin = time.time_ns()
 
     # -- reads --------------------------------------------------------------
 
@@ -217,7 +316,8 @@ class Tracer:
             })
         doc = {"traceEvents": trace_events, "displayTimeUnit": "ms",
                "otherData": {"recorded": self.recorded,
-                             "capacity": self.capacity}}
+                             "capacity": self.capacity,
+                             "origin_ns": self._origin}}
         if path:
             with open(path, "w") as fh:
                 json.dump(doc, fh)
@@ -288,5 +388,6 @@ def export(path: Optional[str] = None) -> dict:
     return TRACER.export(path)
 
 
-__all__ = ["TRACER", "Tracer", "aggregate", "clear", "disable", "enable",
-           "enabled", "events", "export", "span", "traced"]
+__all__ = ["COMPILE_STEPS", "OUTSIDE", "TRACER", "Tracer", "aggregate",
+           "clear", "disable", "enable", "enabled", "events", "export",
+           "span", "traced"]
