@@ -1,8 +1,9 @@
 """Probabilistic set representations of vertex neighborhoods (ProbGraph §II-D).
 
-All builders are pure functions of the padded adjacency and return fixed-size
-per-vertex sketch arrays — the fixed size is the point: it turns skewed set
-algebra into perfectly regular, shardable tensor ops (paper Fig. 1, panel 5).
+Every builder returns fixed-size per-vertex sketch arrays — the fixed size is
+the point: it turns skewed set algebra into perfectly regular, shardable
+tensor ops (paper Fig. 1, panel 5). The Bloom build reads the edge list; the
+k-Hash, 1-Hash and KMV builders are pure functions of the padded adjacency.
 
 Representations:
   * Bloom filter  : uint32[n, words]  (B = 32*words bits, b hash functions)
@@ -88,24 +89,63 @@ def bloom_rows(adj_rows: jax.Array, n: int, words: int, num_hashes: int = 2,
                seed: int = 0) -> jax.Array:
     """Bloom rows for a block of padded adjacency rows (pad value == n).
 
-    The per-chunk body of :func:`build_bloom`, exposed so streaming
-    maintenance can selectively rebuild dirty rows through the exact same
-    code path (results are independent of the rows' padded width).
+    Streaming maintenance rebuilds dirty rows through it; each row equals
+    :func:`build_bloom`'s for the same neighbours (results are independent
+    of the rows' padded width).
     """
     return pack_bits(bloom_bits(adj_rows, n, num_hashes, words * 32, seed))
 
 
-def build_bloom(graph: Graph, words: int, num_hashes: int = 2, seed: int = 0,
-                chunk: int = 4096) -> jax.Array:
-    """Pure-JAX Bloom construction: uint32[n, words].
+def build_bloom(graph: Graph, words: int, num_hashes: int = 2,
+                seed: int = 0) -> jax.Array:
+    """Pure-JAX Bloom construction from the edge list: uint32[n, words].
 
-    Scatters boolean bits per chunk of vertices (duplicate positions are
-    benign for OR), then bit-packs 32→1. Work O(b·Σd_v), depth O(log(b·d))
-    (paper Table V).
+    Each of the b·2m (row, neighbour) entries of ``graph.edges``, taken in
+    both directions, sets one bit per hash function; ``graph.adj`` and its
+    padding are never read. Work O(b·Σd_v) = O(b·2m), depth O(log(b·m))
+    (paper Table V). Rows are bit-identical to :func:`bloom_rows` over the
+    padded adjacency: an OR depends on neither order nor multiplicity.
     """
-    fn = functools.partial(bloom_rows, n=graph.n, words=words,
-                           num_hashes=num_hashes, seed=seed)
-    return _map_vertex_chunks(fn, graph.adj, chunk, (words,), jnp.uint32)
+    n = graph.n
+    return _bloom_from_edges(graph.edges, n=n, words=words,
+                             num_hashes=num_hashes, seed=seed,
+                             one_key=n * words * 32 < 2 ** 31)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "words", "num_hashes",
+                                             "seed", "one_key"))
+def _bloom_from_edges(edges: jax.Array, n: int, words: int, num_hashes: int,
+                      seed: int, one_key: bool) -> jax.Array:
+    """Sort the (row, bit position) pairs, keep the first of each run of
+    equals, and add each kept bit into its word: with no bit twice in a
+    word, the add is an OR. ``one_key`` sorts the int32 key ``row·32W +
+    pos`` (it needs n·32W < 2**31), else the pair sorts lexicographically."""
+    total_bits = words * 32
+    src = jnp.concatenate([edges[:, 0], edges[:, 1]])
+    dst = jnp.concatenate([edges[:, 1], edges[:, 0]])
+    seeds = jnp.arange(num_hashes, dtype=jnp.uint32) + jnp.uint32(seed) * jnp.uint32(0x9E3779B9)
+    pos = (hash_u32(dst[None], seeds[:, None])
+           % jnp.uint32(total_bits)).astype(jnp.int32).reshape(-1)  # [b·2m]
+    rows = jnp.tile(src, num_hashes)
+    if one_key:
+        key = jnp.sort(rows * total_bits + pos)
+        new, word, bit = _run_starts(key), key >> 5, key & 31
+    else:
+        rows, pos = jax.lax.sort((rows, pos), num_keys=2)
+        new = _run_starts(rows) | _run_starts(pos)
+        word, bit = rows * words + (pos >> 5), pos & 31
+    ones = jnp.where(new, jnp.uint32(1) << bit.astype(jnp.uint32),
+                     jnp.uint32(0))
+    out = jnp.zeros(n * words, jnp.uint32).at[word].add(
+        ones, indices_are_sorted=True)
+    return out.reshape(n, words)
+
+
+def _run_starts(x: jax.Array) -> jax.Array:
+    """bool mask of the entries of a 1-D array that differ from the one
+    before (the first entry always)."""
+    return jnp.concatenate([jnp.ones(min(1, x.shape[0]), jnp.bool_),
+                            x[1:] != x[:-1]])
 
 
 def pack_bits(bits: jax.Array) -> jax.Array:
@@ -282,7 +322,8 @@ def build(graph: Graph, kind: str, storage_budget: float = 0.25,
     """Paper Listing 6 entry point: ProbGraph(g, KIND, s)."""
     if kind == "bf":
         w = words if words is not None else bloom_words_for_budget(graph.n, graph.m, storage_budget)
-        with trace.span("sketch.bloom_build", words=w):
+        with trace.span("sketch.bloom_build", words=w,
+                        positions=num_hashes * 2 * graph.m):
             data = build_bloom(graph, w, num_hashes, seed)
         return SketchSet(data=data, kind="bf", num_hashes=num_hashes, k=0,
                          seed=seed, n=graph.n)

@@ -1,4 +1,6 @@
 """Sketch construction: determinism, np/jax twins, membership semantics."""
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -33,6 +35,79 @@ def test_bloom_np_equals_jax(g):
         bf = S.build_bloom(g, words=8, num_hashes=b, seed=5)
         bf_np = S.build_bloom_np(g, words=8, num_hashes=b, seed=5)
         assert np.array_equal(np.asarray(bf), bf_np)
+
+
+def _isolated_er():
+    """Erdős-Rényi with isolated vertices (three at this seed)."""
+    g = G.erdos_renyi(200, 0.02, seed=3)
+    assert int(np.sum(np.asarray(g.deg) == 0)) > 0
+    return g
+
+
+def _hub_kronecker():
+    """Scale-10 Kronecker: d_max 471 against a mean degree of about 21."""
+    g = G.kronecker(10, 16, seed=2)
+    assert g.d_max > 10 * 2 * g.m / g.n
+    return g
+
+
+def _headroom_view():
+    """A ``graph_view`` whose adjacency carries 7 columns of headroom."""
+    base = G.erdos_renyi(120, 0.05, seed=11)
+    wide = G.from_edge_array(base.n, np.asarray(base.edges),
+                             pad_to_max_degree=base.d_max + 7)
+    return G.graph_view(base.n, base.m, wide.deg, wide.adj, wide.edges)
+
+
+def _edgeless():
+    return G.from_edge_array(40, np.zeros((0, 2), np.int64))
+
+
+def _star():
+    """Leaves 1..32 share one neighbour: consecutive rows set equal bits."""
+    return G.from_edge_array(33, np.stack([np.zeros(32, np.int64),
+                                           np.arange(1, 33)], axis=1))
+
+
+BLOOM_GRAPHS = {"er_isolated": _isolated_er, "kronecker_hub": _hub_kronecker,
+                "headroom_view": _headroom_view, "edgeless": _edgeless,
+                "star": _star}
+
+
+@pytest.fixture(scope="module", params=list(BLOOM_GRAPHS))
+def bloom_graph(request):
+    return BLOOM_GRAPHS[request.param]()
+
+
+def _padded_bloom(g, words, b, seed):
+    """The padded-adjacency build: ``bloom_rows`` over chunks of rows."""
+    fn = functools.partial(S.bloom_rows, n=g.n, words=words, num_hashes=b,
+                           seed=seed)
+    return np.asarray(S._map_vertex_chunks(fn, g.adj, 64, (words,),
+                                           jnp.uint32))
+
+
+@pytest.mark.parametrize("seed", [0, 5])
+@pytest.mark.parametrize("b", [1, 2, 3])
+def test_edge_list_bloom_equals_padded_and_host(bloom_graph, b, seed):
+    """The edge-list build sets the same bits as the padded-adjacency rows
+    and the host build, bit for bit."""
+    g = bloom_graph
+    got = np.asarray(S.build_bloom(g, 8, b, seed))
+    assert got.shape == (g.n, 8) and got.dtype == np.uint32
+    assert np.array_equal(got, _padded_bloom(g, 8, b, seed))
+    assert np.array_equal(got, S.build_bloom_np(g, 8, b, seed))
+
+
+@pytest.mark.parametrize("b", [1, 3])
+def test_edge_list_bloom_pair_sort_equals_key_sort(bloom_graph, b):
+    """The lexicographic (row, position) sort, taken where n·32W does not
+    fit an int32 key, sets the same bits as the one-key sort."""
+    g = bloom_graph
+    pair = S._bloom_from_edges(g.edges, n=g.n, words=8, num_hashes=b,
+                               seed=5, one_key=False)
+    assert np.array_equal(np.asarray(pair),
+                          np.asarray(S.build_bloom(g, 8, b, 5)))
 
 
 def test_bloom_membership_no_false_negatives(g):

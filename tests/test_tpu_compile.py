@@ -15,6 +15,7 @@ test worker collects the same tests and only the one given this file loads
 the TPU compiler. Where no topology can be described, the tests skip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,13 +99,22 @@ def _graph(sharding) -> Graph:
 
 
 def test_bloom_build_fits_v5e(one_chip):
-    """The device Bloom build over the padded scale-16 adjacency at the
-    widest budget stays well inside a 16 GB chip (a [rows, d_max, b]
-    position layout once asked for 20 GB)."""
+    """The device Bloom build of the scale-16 graph at the widest budget
+    works from the edge list: its temporaries stay under 1.25 GiB (a
+    bool[n, 32W] bitmap alone is 0.97 GB at W = 462, and a [rows, d_max, b]
+    position layout once asked for 20 GB), and no instruction but a
+    parameter has the padded adjacency's width d_max in its shape."""
     compiled = jax.jit(lambda g: build_bloom(g, WIDTHS[-1])).lower(
         _graph(one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+    assert mem.temp_size_in_bytes < 1.25 * (1 << 30)
+    instruction = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = ")
+    d_max_shape = re.compile(rf"\[(?:\d+,)*{D_MAX}(?:,\d+)*\]")
+    padded = [line.strip() for line in compiled.as_text().splitlines()
+              if instruction.match(line) and "parameter(" not in line
+              and d_max_shape.search(line)]
+    assert not padded, padded[:3]
 
 
 def test_jnp_tc_fold_compiles_for_v5e(one_chip):
