@@ -22,7 +22,6 @@ from .engine import (
     triple_cardinality_ones,
     tuple_cardinality_ones,
     wedge_quad_ones,
-    wedge_triple_ones,
 )
 
 __all__ = [
@@ -32,5 +31,4 @@ __all__ = [
     "pair_cardinality_fn", "plan_for", "pow2_bucket", "resolve_plan",
     "session", "setexpr", "sum_edge_cardinalities",
     "triple_cardinality_ones", "tuple_cardinality_ones", "wedge_quad_ones",
-    "wedge_triple_ones",
 ]

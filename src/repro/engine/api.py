@@ -22,7 +22,6 @@ from .engine import (
     triple_cardinality_ones,
     tuple_cardinality_ones,
     wedge_quad_ones,
-    wedge_triple_ones,
 )
 from .plan import (
     EnginePlan,
@@ -49,5 +48,4 @@ __all__ = [
     "order_edges_by_hub", "pair_cardinality_fn", "plan_for", "pow2_bucket",
     "resolve_plan", "rows", "session", "setexpr", "sum_edge_cardinalities",
     "triple_cardinality_ones", "tuple_cardinality_ones", "wedge_quad_ones",
-    "wedge_triple_ones",
 ]

@@ -14,8 +14,8 @@ Responsibilities (SISA's set-centric batching + GBBS's shared primitives):
     over gathered rows or the equivalent jnp gather (bit-identical
     popcounts).
   * ``session`` — multi-query amortization: build the sketch once, run
-    TC + LCC + clustering + 4-clique over the shared sketch and the shared
-    per-edge cardinality pass.
+    TC + LCC + clustering over the shared sketch and the shared per-edge
+    cardinality pass, and 4-cliques over a cached triangle list.
 """
 from __future__ import annotations
 
@@ -194,30 +194,6 @@ def triple_cardinality_ones(sketch: SketchSet, triples: jax.Array,
     return tuple_cardinality_ones(sketch, triples, plan)
 
 
-def wedge_triple_ones(sketch: SketchSet, u: jax.Array, v: jax.Array,
-                      w_grid: jax.Array, plan: EnginePlan) -> jax.Array:
-    """popcnt(Bu & Bv & Bw) over a wedge grid: u, v int32[C], w int32[C, d]
-    -> int32[C, d] (the 4-clique triple-intersection provider).
-
-    Kernel path flattens to (u, v, w) triples for the 3-way fused pass;
-    the jnp path keeps the broadcast form so the u/v rows are
-    gathered once per edge rather than once per wedge. Identical integer
-    popcounts either way.
-    """
-    c, d = w_grid.shape
-    if plan.use_kernel:
-        triples = jnp.stack([
-            jnp.broadcast_to(u[:, None], (c, d)).reshape(-1),
-            jnp.broadcast_to(v[:, None], (c, d)).reshape(-1),
-            w_grid.reshape(-1)], axis=1)
-        return triple_cardinality_ones(sketch, triples, plan).reshape(c, d)
-    ru = jnp.take(sketch.data, u, axis=0)[:, None, :]
-    rv = jnp.take(sketch.data, v, axis=0)[:, None, :]
-    rw = jnp.take(sketch.data, w_grid, axis=0)
-    return jnp.sum(jax.lax.population_count(ru & rv & rw), axis=-1
-                   ).astype(jnp.int32)
-
-
 def wedge_quad_ones(sketch: SketchSet, u: jax.Array, v: jax.Array,
                     w_grid: jax.Array, x_grid: jax.Array,
                     plan: EnginePlan) -> jax.Array:
@@ -349,7 +325,8 @@ def _carry_scatter_cards(old_cards, carry, pos, sub, *, m_new):
 
 class MiningSession:
     """Amortizes one sketch build + one per-edge cardinality pass across
-    TC, LCC, Jarvis-Patrick and 4-clique queries on the same graph."""
+    TC, LCC and Jarvis-Patrick, and one triangle list across 4-clique
+    queries, on the same graph."""
 
     def __init__(self, graph: Graph, sketch: Optional[SketchSet],
                  plan: EnginePlan):
@@ -357,18 +334,21 @@ class MiningSession:
         self.sketch = sketch
         self.plan = plan
         self._edge_cards: Optional[jax.Array] = None
+        self._triangles: Optional[tuple] = None
 
     def fork(self) -> "MiningSession":
         """Copy-on-write twin sharing this session's state by reference.
 
         Every field a session mutates (``graph``, ``sketch``,
-        ``_edge_cards``) is only ever *rebound*, never edited in place, so a
-        fork plus :meth:`refresh` builds the next version's session while
-        the original keeps serving the old one untouched — the
-        snapshot-isolation seam ``StreamSession`` publishes through.
+        ``_edge_cards``, ``_triangles``) is only ever *rebound*, never
+        edited in place, so a fork plus :meth:`refresh` builds the next
+        version's session while the original keeps serving the old one
+        untouched — the snapshot-isolation seam ``StreamSession``
+        publishes through.
         """
         new = MiningSession(self.graph, self.sketch, self.plan)
         new._edge_cards = self._edge_cards
+        new._triangles = self._triangles
         return new
 
     def edge_cardinalities(self) -> jax.Array:
@@ -402,10 +382,31 @@ class MiningSession:
                                   threshold, plan=self.plan,
                                   edge_cards=self.edge_cardinalities())
 
-    def four_clique_count(self, **kw) -> jax.Array:
-        """Scalar 4-clique count estimate (3-way sketch intersections)."""
+    def triangles(self) -> tuple:
+        """Cached triangle list ``(int32[T_cap, 3], T)``: each triangle once,
+        a < b < c in (degree, id) rank order, zero rows past T
+        (:func:`core.algorithms.cliques.triangle_list`)."""
+        if self._triangles is None:
+            from ..core.algorithms.cliques import triangle_list
+            with trace.span("engine.triangles") as sp:
+                tris, count, wedges = triangle_list(self.graph)
+                sp.set(wedges=wedges, triangles=count,
+                       capacity=int(tris.shape[0]))
+                self._triangles = (sp.fence(tris), count)
+        return self._triangles
+
+    def four_clique_count(self, exact_closing_test: bool = True,
+                          **kw):
+        """Scalar 4-clique count estimate (3-way sketch intersections over
+        the cached triangle list); ``return_ones=True`` also returns the
+        exact Σ of the 3-way AND popcounts
+        (:func:`core.algorithms.cliques.four_clique_count`)."""
         from ..core.algorithms.cliques import four_clique_count
-        return four_clique_count(self.graph, self.sketch, plan=self.plan, **kw)
+        with trace.span("engine.four_clique_count") as sp:
+            tris = self.triangles() if exact_closing_test else None
+            return sp.fence(four_clique_count(
+                self.graph, self.sketch, plan=self.plan,
+                exact_closing_test=exact_closing_test, triangles=tris, **kw))
 
     def five_clique_count(self, **kw) -> jax.Array:
         """Scalar 5-clique count estimate (4-way sketch intersections)."""
@@ -465,6 +466,7 @@ class MiningSession:
         ``graph.edges`` when its cached cardinality is still valid (neither
         endpoint's neighborhood, degree, or sketch row changed), or -1 to
         recompute. With ``carry_index=None`` the whole cache is dropped.
+        The triangle list is always dropped (rebuilt lazily).
         A :class:`DeviceCarry` keeps the whole exchange on device (carried
         values are gathered by the device permutation, only the delta-sized
         recompute positions were uploaded). Returns the number of per-edge
@@ -483,6 +485,7 @@ class MiningSession:
     def _refresh(self, graph, sketch, carry_index):
         old_cards = self._edge_cards
         self.graph = graph
+        self._triangles = None
         if sketch is not None:
             self.sketch = sketch
         if (old_cards is None or carry_index is None

@@ -3,7 +3,8 @@
 Interpret mode runs a Pallas kernel body in Python and accepts block shapes
 and DMAs that the chip's compiler refuses, and the CPU accepts layouts that
 need more than a chip's memory. These tests compile the fused popcount
-pass, the Bloom build and the jnp TC fold for a described (not attached)
+pass, the Bloom build, the jnp TC fold and the 4-clique path (triangle
+listing, 3-way AND over the list) for a described (not attached)
 ``v5e:2x2`` at the shapes of
 the Graph500 Kronecker graph at scale 16, edge factor 16 (n = 65,536,
 m = 910,200, d_max = 9,729), whose Bloom rows are W = 8, 116 and 462 words
@@ -14,6 +15,7 @@ The topology is described inside a module fixture, never at import, so every
 test worker collects the same tests and only the one given this file loads
 the TPU compiler. Where no topology can be described, the tests skip.
 """
+import math
 import os
 import re
 
@@ -32,6 +34,7 @@ N, M, D_MAX = 65_536, 910_200, 9_729        # Graph500 scale 16, edge factor 16
 WIDTHS = (8, 116, 462)                       # budgets 0.25, 4, 16 at scale 16
 TUPLES = 1 << 16
 SWEEP_ROWS = 8 * 512                         # 8 seeds x sweep_cap 512
+T_CAP = 1 << 24                              # pow2_bucket of its 15.6M triangles
 
 
 @pytest.fixture(scope="module")
@@ -109,12 +112,66 @@ def test_bloom_build_fits_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
     assert mem.temp_size_in_bytes < 1.25 * (1 << 30)
+    padded = _d_max_instructions(compiled)
+    assert not padded, padded[:3]
+
+
+def _d_max_instructions(compiled) -> list:
+    """Instructions other than parameters with d_max in their shape."""
     instruction = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = ")
     d_max_shape = re.compile(rf"\[(?:\d+,)*{D_MAX}(?:,\d+)*\]")
-    padded = [line.strip() for line in compiled.as_text().splitlines()
-              if instruction.match(line) and "parameter(" not in line
-              and d_max_shape.search(line)]
-    assert not padded, padded[:3]
+    return [line.strip() for line in compiled.as_text().splitlines()
+            if instruction.match(line) and "parameter(" not in line
+            and d_max_shape.search(line)]
+
+
+def test_four_clique_programs_fit_v5e(one_chip, monkeypatch):
+    """The 4-clique path of the scale-16 graph: the oriented CSR, its edges
+    grouped by row width, the closing vertices of every oriented edge at
+    the graph's widest oriented row (247 -> 256), the flat list of T_CAP
+    rows, and the 3-way AND over the list on the jnp and the Mosaic path.
+    They read ``indptr``/``indices``, never the padded adjacency: no
+    instruction but a parameter has d_max in its shape. Temporaries stay
+    under 1.25 GiB (the closing-vertex table alone is 0.9 GiB)."""
+    from repro.core.algorithms import cliques as CL
+    from repro.core.graph import degree_oriented_csr
+    from repro.kernels import fused_expr
+
+    def vec(*shape):
+        return _sds(shape, jnp.int32, one_chip)
+
+    rows = M + CL.EDGE_CHUNK
+    classes = math.isqrt(2 * M).bit_length() + 2
+    programs = [
+        degree_oriented_csr.lower(vec(N + 1), vec(2 * M)),
+        CL._edge_classes.lower(vec(N + 1), vec(M), vec(M)),
+        CL._closing_vertices.lower(vec(N + 1), vec(M), vec(M), vec(M),
+                                   vec(M), vec(classes), None, width=256,
+                                   num_hashes=0, seed=0),
+        CL._triangle_rows.lower(vec(M), vec(M), vec(rows, 256), vec(rows),
+                                capacity=T_CAP, cols=128),
+    ]
+    graph = _graph(one_chip)
+    sketch = SketchSet(data=_sds((N, WIDTHS[1]), jnp.uint32, one_chip),
+                       kind="bf", num_hashes=2, k=0, seed=0, n=N)
+    plan = ENG.plan_for(graph, sketch)
+    # the engine's compiled AND, lowered for the chip and not interpreted
+    monkeypatch.setattr(fused_expr, "default_interpret", lambda: False)
+    setexpr.cache_clear()
+    try:
+        for use_kernel in (False, True):
+            programs.append(CL._bloom_triple_sums.lower(
+                sketch, vec(T_CAP, 3), vec(),
+                plan=plan.with_(use_kernel=use_kernel),
+                chunk=1 << (plan.edge_chunk.bit_length() - 1)))
+    finally:
+        setexpr.cache_clear()
+    for lowered in programs:
+        compiled = lowered.compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.25 * (1 << 30)
+        padded = _d_max_instructions(compiled)
+        assert not padded, padded[:3]
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_jnp_tc_fold_compiles_for_v5e(one_chip):
