@@ -11,6 +11,7 @@ has depth O(diameter·log n) and is the standard XLA-friendly CC.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -20,10 +21,21 @@ from ... import engine as eng
 from ...obs import trace
 from ..graph import Graph
 from ..sketches import SketchSet
+from .similarity import similarity_from_cardinalities
 
 
+@functools.partial(jax.jit, static_argnames=("n", "max_iters"))
 def _connected_components(n: int, edges: jax.Array, keep: jax.Array,
-                          max_iters: int = 200) -> jax.Array:
+                          max_iters: int = 200):
+    """Min-label propagation over the kept edges, as one compiled program.
+
+    Returns ``(labels int32[n], num_clusters int32, iterations int32)``:
+    each vertex's component-minimum id (once the loop converges within
+    ``max_iters``), the number of vertices that are their own label, and
+    the loop's iteration count. A module-level jit keys the program on the
+    input shapes and the static ``n``/``max_iters``, so repeated calls
+    (one per mining session) neither retrace nor relower the loop.
+    """
     u, v = edges[:, 0], edges[:, 1]
 
     def body(state):
@@ -44,10 +56,23 @@ def _connected_components(n: int, edges: jax.Array, keep: jax.Array,
         _, changed, it = state
         return changed & (it < max_iters)
 
-    labels0 = jnp.arange(n, dtype=jnp.int32)
-    labels, _, _ = jax.lax.while_loop(
-        cond, body, (labels0, jnp.bool_(True), jnp.int32(0)))
-    return labels
+    ids = jnp.arange(n, dtype=jnp.int32)
+    labels, _, iterations = jax.lax.while_loop(
+        cond, body, (ids, jnp.bool_(True), jnp.int32(0)))
+    # every vertex no kept edge touches is its own cluster (the paper counts
+    # all clusters)
+    return labels, jnp.sum(labels == ids), iterations
+
+
+@functools.partial(jax.jit, static_argnames=("similarity",))
+def _keep_mask(deg: jax.Array, edges: jax.Array, edge_cards: jax.Array,
+               threshold: jax.Array, similarity: str) -> jax.Array:
+    """bool[m]: each edge's similarity score passes ``threshold`` (float32,
+    an argument so that every threshold shares one program)."""
+    du = jnp.take(deg, edges[:, 0]).astype(jnp.float32)
+    dv = jnp.take(deg, edges[:, 1]).astype(jnp.float32)
+    score = similarity_from_cardinalities(edge_cards, du, dv, similarity)
+    return score >= threshold
 
 
 def jarvis_patrick(graph: Graph, sketch: Optional[SketchSet] = None,
@@ -60,20 +85,16 @@ def jarvis_patrick(graph: Graph, sketch: Optional[SketchSet] = None,
     (ratio ≥ threshold). ``edge_cards`` lets a MiningSession reuse its
     shared per-edge cardinality pass.
     """
-    from .similarity import similarity_from_cardinalities
-
     edges = graph.edges
     if edge_cards is None:
         plan = eng.resolve_plan(plan, graph, sketch, kw)
         edge_cards = eng.edge_cardinalities(graph, sketch, plan)
     with trace.span("jp.similarity"):
-        du = jnp.take(graph.deg, edges[:, 0]).astype(jnp.float32)
-        dv = jnp.take(graph.deg, edges[:, 1]).astype(jnp.float32)
-        score = similarity_from_cardinalities(edge_cards, du, dv, similarity)
-        keep = score >= threshold
-    with trace.span("jp.label_propagation"):
-        labels = _connected_components(graph.n, edges, keep)
-    # count distinct labels among non-isolated semantics: every vertex is its
-    # own cluster when no kept edge touches it (paper counts all clusters)
-    num = jnp.sum(labels == jnp.arange(graph.n, dtype=jnp.int32))
+        keep = _keep_mask(graph.deg, edges, edge_cards,
+                          jnp.asarray(threshold, jnp.float32), similarity)
+    with trace.span("jp.label_propagation") as sp:
+        labels, num, iterations = _connected_components(graph.n, edges, keep)
+        # a host read, so only while tracing and never under an outer jit
+        if trace.enabled() and not isinstance(iterations, jax.core.Tracer):
+            sp.set(iterations=int(iterations))
     return labels, num

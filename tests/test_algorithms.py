@@ -1,11 +1,16 @@
 """Graph algorithms: exact oracles + estimator accuracy on fixed seeds."""
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import graph as G, sketches as S, exact as X
 from repro.core import (triangle_count, four_clique_count, jarvis_patrick,
                         pair_similarity, link_prediction_effectiveness)
+from repro.core.algorithms.clustering import _connected_components, _keep_mask
+from repro.core.algorithms.similarity import similarity_from_cardinalities
 from repro.core.algorithms.tc import local_clustering_coefficient
 
 
@@ -73,6 +78,130 @@ def test_clustering_planted_partition():
     labels, num = jarvis_patrick(g, None, "common", 2.0)
     # strong communities: far fewer clusters than vertices
     assert int(num) < g.n // 3
+
+
+def _label_propagation_np(n, edges, keep, max_iters):
+    """numpy replay of ``_connected_components``' capped synchronous loop:
+    scatter-min over the kept edges, one pointer jump, stop when unchanged."""
+    u, v = edges[:, 0], edges[:, 1]
+    labels, it, changed = np.arange(n, dtype=np.int32), 0, True
+    while changed and it < max_iters:
+        lu, lv = labels[u], labels[v]
+        low = np.minimum(lu, lv)
+        new = labels.copy()
+        np.minimum.at(new, u, np.where(keep, low, lu))
+        np.minimum.at(new, v, np.where(keep, low, lv))
+        new = new[new]
+        changed = bool((new != labels).any())
+        labels, it = new, it + 1
+    return labels, it
+
+
+def _component_min_np(n, edges, keep):
+    """Union-find over the kept edges, each root the least id of its set."""
+    parent = list(range(n))
+
+    def find(x):
+        while parent[x] != x:
+            parent[x] = parent[parent[x]]
+            x = parent[x]
+        return x
+
+    for (a, b), k in zip(edges.tolist(), keep.tolist()):
+        if k:
+            ra, rb = find(a), find(b)
+            parent[max(ra, rb)] = min(ra, rb)
+    return np.array([find(x) for x in range(n)], dtype=np.int32)
+
+
+def _random_edges(n, m, seed):
+    rng = np.random.default_rng(seed)
+    e = np.sort(rng.integers(0, n, size=(m, 2)), axis=1)
+    return np.unique(e[e[:, 0] < e[:, 1]], axis=0).astype(np.int32)
+
+
+def _path_edges(n, seed=None):
+    """A path through all n vertices, in id order or (with a seed) in a
+    shuffled order."""
+    order = (np.arange(n) if seed is None
+             else np.random.default_rng(seed).permutation(n))
+    return np.sort(np.stack([order[:-1], order[1:]], axis=1),
+                   axis=1).astype(np.int32)
+
+
+_CC_CASES = {
+    # name: (n, edges, keep, max_iters); None keeps all edges
+    "random_keep_all": (300, _random_edges(300, 260, 1), None, 200),
+    "random_keep_none": (300, _random_edges(300, 260, 1), False, 200),
+    "random_keep_random": (300, _random_edges(300, 400, 2), "random", 200),
+    "isolated_vertex": (7, np.array([[0, 1], [1, 2], [2, 4], [5, 6]],
+                                    np.int32), None, 200),
+    "long_path": (1500, _path_edges(1500), None, 200),
+    "shuffled_path": (256, _path_edges(256, 3), None, 200),
+    "capped_early": (1500, _path_edges(1500, 3), None, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CC_CASES))
+def test_label_propagation_matches_numpy(case):
+    n, edges, keep, max_iters = _CC_CASES[case]
+    m = edges.shape[0]
+    if keep is None:
+        keep = np.ones(m, bool)
+    elif keep is False:
+        keep = np.zeros(m, bool)
+    else:
+        keep = np.random.default_rng(4).random(m) < 0.6
+    labels, num, iters = _connected_components(
+        n, jnp.asarray(edges), jnp.asarray(keep), max_iters=max_iters)
+    want, want_iters = _label_propagation_np(n, edges, keep, max_iters)
+    labels = np.asarray(labels)
+    assert labels.dtype == np.int32
+    np.testing.assert_array_equal(labels, want)
+    assert int(iters) == want_iters
+    assert int(num) == int(np.sum(want == np.arange(n)))
+    if case == "capped_early":
+        assert want_iters == max_iters
+        assert (labels != _component_min_np(n, edges, keep)).any()
+    else:
+        assert want_iters < max_iters
+        np.testing.assert_array_equal(labels,
+                                      _component_min_np(n, edges, keep))
+    if case == "long_path":
+        # scatter-min alone moves a label one hop a round; the pointer jump
+        # crosses the 1,499-edge chain in a few dozen
+        assert 2 < want_iters < 64
+
+
+@pytest.mark.parametrize("similarity,threshold",
+                         [("common", 2.0), ("jaccard", 0.05),
+                          ("overlap", 0.3), ("total", 9.0)])
+@pytest.mark.parametrize("kind", ["exact", "bf"])
+def test_keep_mask_matches_eager_scoring(g, kind, similarity, threshold):
+    from repro import engine as eng
+    sk = None if kind == "exact" else S.build(g, "bf", 0.33, num_hashes=2,
+                                              seed=1)
+    cards = eng.session(g, sk).edge_cardinalities()
+    du = jnp.take(g.deg, g.edges[:, 0]).astype(jnp.float32)
+    dv = jnp.take(g.deg, g.edges[:, 1]).astype(jnp.float32)
+    want = similarity_from_cardinalities(cards, du, dv, similarity) >= threshold
+    got = _keep_mask(g.deg, g.edges, cards, jnp.asarray(threshold, jnp.float32),
+                     similarity)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert 0 < int(jnp.sum(got)) < g.m or similarity == "total"
+
+
+@pytest.mark.parametrize("similarity,threshold",
+                         [("common", 2.0), ("jaccard", 0.05), ("overlap", 0.3)])
+def test_jarvis_patrick_under_outer_jit_matches_eager(g, similarity,
+                                                      threshold):
+    sk = S.build(g, "bf", 0.33, num_hashes=2, seed=1)
+    labels, num = jarvis_patrick(g, sk, similarity, threshold)
+    fn = jax.jit(functools.partial(jarvis_patrick, similarity=similarity,
+                                   threshold=threshold))
+    jl, jn = fn(g, sk)
+    np.testing.assert_array_equal(np.asarray(jl), np.asarray(labels))
+    assert int(jn) == int(num)
 
 
 def test_similarity_measures_exact(g):

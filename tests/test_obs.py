@@ -527,6 +527,38 @@ def test_mining_session_spans(tracing):
     }
 
 
+def test_jarvis_patrick_compiles_once_across_sessions(tracing):
+    """JP's two programs are module-level jits: a second session on the same
+    graph and sketch runs them with no compile step under the ``jp.*``
+    spans, and the loop's iteration count lands on its span."""
+    from repro import engine as eng
+
+    g = G.kronecker(7, 8, seed=2)
+    sketch = eng.session(g, "bf", storage_budget=1.0).sketch
+    jp_spans = ("jp.similarity", "jp.label_propagation")
+
+    def jp_compiles():
+        return sum(c.value for labels, c in
+                   REGISTRY.labelled("compile_step_total").items()
+                   if dict(labels)["span"] in jp_spans)
+
+    first = eng.session(g, sketch).jarvis_patrick("jaccard", 0.05)
+    jax.block_until_ready(first)
+    before, n_events = jp_compiles(), len(trace.events())
+    second = eng.session(g, sketch).jarvis_patrick("jaccard", 0.05)
+    jax.block_until_ready(second)
+    assert jp_compiles() == before
+    new = trace.events()[n_events:]
+    assert [e for e in new
+            if e["name"].startswith("jax.") and e["parent"] in jp_spans] == []
+    (lp,) = [e for e in new if e["name"] == "jp.label_propagation"]
+    assert isinstance(lp["args"]["iterations"], int)
+    assert lp["args"]["iterations"] >= 1
+    np.testing.assert_array_equal(np.asarray(first[0]),
+                                  np.asarray(second[0]))
+    assert int(first[1]) == int(second[1])
+
+
 # ---------------------------------------------------------------------------
 # bench record schema (benchmarks.common)
 # ---------------------------------------------------------------------------
