@@ -5,8 +5,7 @@ machine-diffable ``BENCH_<suite>.json`` per suite to ``--json-dir``: the
 suite's schema-consistent records (``{"name", "wall_s", "metrics"}``) plus a
 per-stage span breakdown aggregated from the observability tracer (delta
 apply, sketch maintenance, cache, flush, kernel execute — see
-docs/OBSERVABILITY.md). Heavy roofline cells come from the dry-run artifacts
-(benchmarks.roofline), not recomputed here.
+docs/OBSERVABILITY.md).
 
 ``--smoke`` runs the fast subset (kernel micro + engine suites) — the
 nightly-CI sanity pass; ``--only NAME`` runs a single suite by name.
@@ -43,7 +42,7 @@ def main() -> None:
 
     from . import (accuracy_pairs, adaptive_bloom, algo_speedup, common,
                    construction, engine_bench, heuristics, kernels_bench,
-                   localcluster, roofline, scaling, serving, setexpr_bench,
+                   localcluster, scaling, serving, setexpr_bench,
                    stream_bench, tc_estimators)
     suites = [
         ("kernels", kernels_bench.run),
@@ -59,7 +58,6 @@ def main() -> None:
         ("tableV_construction", construction.run),
         ("fig8_scaling", scaling.run),
         ("adaptive_bloom", adaptive_bloom.run),
-        ("roofline", roofline.run),
     ]
     smoke_suites = {"kernels", "setexpr", "engine", "stream", "localcluster"}
     if args.only is not None:

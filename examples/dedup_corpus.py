@@ -1,7 +1,7 @@
-"""MinHash near-duplicate dedup in the LM data pipeline (DESIGN.md §4.1).
+"""MinHash near-duplicate document dedup.
 
-The ProbGraph technique applied where production LM stacks actually use it:
-k-Hash sketches over document shingles + the paper's exponential bound
+The ProbGraph technique applied to a document corpus: k-Hash sketches
+over document shingles + the paper's exponential bound
 (Prop IV.2) to size k for a target false-match rate, then banded LSH to find
 candidates.
 

@@ -43,23 +43,11 @@ def bf_intersection_and_from_ones(ones: jax.Array, total_bits: int, num_hashes: 
     return -(total_bits / num_hashes) * jnp.log1p(-ones / total_bits)
 
 
-def bf_intersection_limit(row_x: jax.Array, row_y: jax.Array, num_hashes: int) -> jax.Array:
-    """|X∩Y|_L (Eq. 4): ones(AND)/b — the B→∞ limit of the AND estimator."""
-    return _popcount_words(row_x & row_y).astype(jnp.float32) / num_hashes
-
-
 def bf_intersection_or(row_x: jax.Array, row_y: jax.Array, num_hashes: int,
                        size_x: jax.Array, size_y: jax.Array) -> jax.Array:
     """|X∩Y|_OR (Eq. 29, Swamidass prior work): |X|+|Y| - |X∪Y|_S via OR."""
     union_est = bf_size_swamidass(row_x | row_y, num_hashes)
     return size_x.astype(jnp.float32) + size_y.astype(jnp.float32) - union_est
-
-
-def bf_false_positive_rate(row: jax.Array, num_hashes: int) -> jax.Array:
-    """p_f = (ones/B)^b — per-sketch false-positive probability."""
-    total_bits = row.shape[-1] * 32
-    frac = _popcount_words(row).astype(jnp.float32) / total_bits
-    return frac ** num_hashes
 
 
 # ----------------------------------------------------------------------------
@@ -185,34 +173,3 @@ def kmv_intersection(kx: jax.Array, ky: jax.Array, size_x, size_y) -> jax.Array:
     union = kmv_union_size(kx, ky)
     est = size_x.astype(jnp.float32) + size_y.astype(jnp.float32) - union
     return jnp.maximum(est, 0.0)
-
-
-# ----------------------------------------------------------------------------
-# Uniform pair-estimator dispatch (used by algorithms & benchmarks)
-# ----------------------------------------------------------------------------
-
-def pair_estimator(kind: str):
-    """Returns fn(sketch_rows_u, sketch_rows_v, deg_u, deg_v, ctx) -> float32[...]."""
-    def bf_and(ru, rv, du, dv, ctx):
-        return bf_intersection_and(ru, rv, ctx["num_hashes"])
-
-    def bf_l(ru, rv, du, dv, ctx):
-        return bf_intersection_limit(ru, rv, ctx["num_hashes"])
-
-    def bf_or(ru, rv, du, dv, ctx):
-        return bf_intersection_or(ru, rv, ctx["num_hashes"], du, dv)
-
-    def kh(ru, rv, du, dv, ctx):
-        return khash_intersection(ru, rv, du, dv, ctx["n"])
-
-    def oneh(ru, rv, du, dv, ctx):
-        hx = ctx["hash_of"](ru)
-        hy = ctx["hash_of"](rv)
-        return onehash_intersection(ru, rv, hx, hy, du, dv, ctx["n"], ctx.get("variant", "union"))
-
-    def kmv(ru, rv, du, dv, ctx):
-        return kmv_intersection(ru, rv, du, dv)
-
-    table = {"bf": bf_and, "bf_and": bf_and, "bf_l": bf_l, "bf_or": bf_or,
-             "kh": kh, "1h": oneh, "kmv": kmv}
-    return table[kind]

@@ -35,16 +35,6 @@ def hash_u32(x: jax.Array, seed) -> jax.Array:
     return fmix32(x ^ fmix32(seed * _GOLDEN + jnp.uint32(1)))
 
 
-def hash_family(x: jax.Array, num_fns: int, seed) -> jax.Array:
-    """Evaluate ``num_fns`` independent hash functions on x.
-
-    Returns uint32 array of shape ``x.shape + (num_fns,)``.
-    """
-    seeds = jnp.arange(num_fns, dtype=jnp.uint32) + jnp.asarray(seed, jnp.uint32) * _GOLDEN
-    # broadcast: x[..., None] ^ per-fn tweak
-    return hash_u32(x[..., None] * jnp.uint32(1) + jnp.uint32(0), seeds)
-
-
 def hash_unit_interval(x: jax.Array, seed) -> jax.Array:
     """Hash keys to (0, 1] as float32 (for KMV sketches)."""
     h = hash_u32(x, seed)

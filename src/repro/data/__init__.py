@@ -1,4 +1,3 @@
-from .pipeline import SyntheticLMData, TokenBatcher
 from .dedup import minhash_dedup, document_sketches
 
-__all__ = ["SyntheticLMData", "TokenBatcher", "minhash_dedup", "document_sketches"]
+__all__ = ["minhash_dedup", "document_sketches"]

@@ -1,5 +1,4 @@
-"""MinHash near-duplicate dedup — the ProbGraph technique inside the LM
-data pipeline (DESIGN.md §4.1).
+"""MinHash near-duplicate document dedup with the ProbGraph sketches.
 
 Documents -> w-gram shingles -> k-Hash MinHash sketches (core.hashing, same
 murmur3 finalizer as the graph sketches) -> LSH banding for candidate pairs

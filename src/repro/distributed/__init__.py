@@ -1,6 +1,3 @@
-from . import fault, sharding
+from . import sharding
 
-# NOTE: `step` imports repro.models (which imports distributed.sharding);
-# import it explicitly as `repro.distributed.step` to avoid a cycle here.
-
-__all__ = ["sharding", "fault"]
+__all__ = ["sharding"]

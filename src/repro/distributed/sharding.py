@@ -1,14 +1,11 @@
-"""Logical-axis sharding: rules mapping logical tensor axes -> mesh axes.
+"""Edge-axis sharding of the mining fold: logical axes -> mesh axes.
 
-Params and activations are annotated with *logical* axis names ("embed",
-"heads", "ff", "expert", "batch", "seq", ...). A rule-set maps those to
-physical mesh axes ("pod", "data", "model"). This is the MaxText/Flax
-partitioning pattern, kept dependency-free.
-
-The active rule-set + mesh are installed via `use_rules(...)`; model code
-calls `shard_as(x, "batch", "seq", "embed")` which becomes a
-`with_sharding_constraint` when a mesh is active and a no-op otherwise
-(single-device smoke tests).
+The engine names the axis it splits, ``"edge"``, and a rule maps it onto
+the physical mesh axes ("pod", "data", "model"). ``use_rules(mesh)``
+installs the mesh for the calling thread; ``engine._edge_shards`` reads it
+back through ``active_mesh()`` and asks ``spec_for(("edge",))`` which of
+its axes the edge list splits over. Without an active mesh the sharded
+plan falls back to the one-device fold.
 """
 from __future__ import annotations
 
@@ -16,39 +13,13 @@ import contextlib
 import threading
 from typing import Dict, Optional, Sequence, Tuple, Union
 
-import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 Axis = Union[str, Tuple[str, ...], None]
 
-# Default production rule-set for a ("pod", "data", "model") or
-# ("data", "model") mesh. "fsdp" is the param shard axis for ZeRO-3-style
-# fully-sharded params (maps to "data").
-BASE_RULES: Dict[str, Axis] = {
-    # activations
-    "batch": ("pod", "data"),
-    "seq": None,
-    "decode_cache_seq": "model",
-    "embed_act": None,
-    # params
-    "vocab": "model",
-    "embed": None,
-    "embed_fsdp": "data",          # FSDP shard dim for 2D+ params
-    "heads": "model",
-    "kv_heads": "model",
-    "head_dim": None,
-    "head_dim_tp": "model",        # fallback TP when heads % model != 0
-    "ff": "model",
-    "expert": "model",
-    "q_lora": None,
-    "kv_lora": None,
-    "ssm_inner": "model",
-    "ssm_state": None,
-    "conv": None,
-    "layers": None,
-    "moe_capacity": None,
-    # graph mining (repro.engine): edge lists shard over every mesh axis —
-    # fixed-size sketches make per-edge work uniform, so any split balances
+# Edge lists shard over every mesh axis: fixed-size sketches make per-edge
+# work uniform, so any split balances.
+RULES: Dict[str, Axis] = {
     "edge": ("pod", "data", "model"),
 }
 
@@ -56,7 +27,6 @@ BASE_RULES: Dict[str, Axis] = {
 class _Ctx(threading.local):
     def __init__(self):
         self.mesh: Optional[Mesh] = None
-        self.rules: Dict[str, Axis] = dict(BASE_RULES)
 
 
 _CTX = _Ctx()
@@ -68,16 +38,14 @@ def active_mesh() -> Optional[Mesh]:
 
 
 @contextlib.contextmanager
-def use_rules(mesh: Optional[Mesh], overrides: Optional[Dict[str, Axis]] = None):
-    prev_mesh, prev_rules = _CTX.mesh, _CTX.rules
-    rules = dict(BASE_RULES)
-    if overrides:
-        rules.update(overrides)
-    _CTX.mesh, _CTX.rules = mesh, rules
+def use_rules(mesh: Optional[Mesh]):
+    """Install ``mesh`` as this thread's active mesh for the block."""
+    prev = _CTX.mesh
+    _CTX.mesh = mesh
     try:
         yield
     finally:
-        _CTX.mesh, _CTX.rules = prev_mesh, prev_rules
+        _CTX.mesh = prev
 
 
 def _filter_axes(mesh: Mesh, phys: Axis, dim_size: int, used: set) -> Axis:
@@ -106,9 +74,14 @@ def spec_for(logical_axes: Sequence[Optional[str]],
              dim_sizes: Optional[Sequence[int]] = None,
              mesh: Optional[Mesh] = None,
              rules: Optional[Dict[str, Axis]] = None) -> P:
-    """Logical axes -> PartitionSpec under the active (or given) rules."""
+    """Logical axes -> PartitionSpec under ``rules`` (default ``RULES``).
+
+    Mesh axes missing from the mesh are dropped, as are axes that an
+    earlier dimension already took; with ``dim_sizes`` an axis is kept
+    only while the product of kept axis sizes divides the dimension.
+    """
     mesh = mesh or _CTX.mesh
-    rules = rules or _CTX.rules
+    rules = rules or RULES
     used: set = set()
     parts = []
     for i, name in enumerate(logical_axes):
@@ -125,29 +98,3 @@ def spec_for(logical_axes: Sequence[Optional[str]],
                 phys = axes if len(axes) > 1 else (axes[0] if axes else None)
         parts.append(phys)
     return P(*parts)
-
-
-def shard_as(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
-    """Constrain activation sharding (no-op without an active mesh)."""
-    mesh = _CTX.mesh
-    if mesh is None:
-        return x
-    spec = spec_for(logical_axes, dim_sizes=x.shape, mesh=mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def sharding_for_tree(axes_tree, shapes_tree, mesh: Mesh,
-                      overrides: Optional[Dict[str, Axis]] = None):
-    """NamedSharding pytree for a params tree given its logical-axes tree."""
-    rules = dict(BASE_RULES)
-    if overrides:
-        rules.update(overrides)
-
-    def one(axes, shape):
-        spec = spec_for(axes, dim_sizes=shape.shape if hasattr(shape, "shape") else shape,
-                        mesh=mesh, rules=rules)
-        return NamedSharding(mesh, spec)
-
-    return jax.tree.map(one, axes_tree, shapes_tree,
-                        is_leaf=lambda x: isinstance(x, tuple) and all(
-                            isinstance(a, (str, type(None))) for a in x))

@@ -2,7 +2,7 @@ import os
 import sys
 
 # Make src/ importable without install; keep the default single CPU device
-# (the dry-run driver sets its own device count in a separate process).
+# (the multi-device system test sets its own device count in a subprocess).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np

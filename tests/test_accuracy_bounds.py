@@ -18,11 +18,14 @@ each layer stays in the fast gate as a smoke.
 """
 import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import engine as eng
-from repro.core import bounds, graph as G, sketches as S
+from repro.core import bounds, estimators as E, graph as G, sketches as S
+from repro.core.hashing import np_hash_u32
 
 CONF = 0.1      # 90%-confidence intervals
 SKETCH_SEED = 0
@@ -133,3 +136,82 @@ def test_tc_ninety_percent_accuracy_smoke(name, kind, kw):
 @pytest.mark.parametrize("name,kind,kw", NINETY_SLOW)
 def test_tc_ninety_percent_accuracy(name, kind, kw):
     assert_ninety(name, kind, kw)
+
+
+# ---------------------------------------------------------------------------
+# layer 3: the error scales the streaming error budget reads, against
+# seeded trials of the estimators they stand for
+# ---------------------------------------------------------------------------
+
+TRIALS = 300
+_GOLDEN = 0x9E3779B9
+
+
+def _trial_pairs(seed, inter, private):
+    """TRIALS pairs of sets sharing ``inter`` elements, each with
+    ``private`` more of its own: uint32[TRIALS, inter + private] each."""
+    rng = np.random.default_rng(seed)
+    el = np.stack([rng.choice(1 << 30, size=inter + 2 * private, replace=False)
+                   for _ in range(TRIALS)]).astype(np.uint32)
+    x = el[:, :inter + private]
+    y = np.concatenate([el[:, :inter], el[:, inter + private:]], axis=1)
+    return x, y
+
+
+def _bloom_rows(sets, words, b):
+    """Bloom rows of each row of ``sets``, hashed as ``core.sketches``
+    hashes (function i seeded with i, sketch seed 0)."""
+    trials, size = sets.shape
+    out = np.zeros((trials, words), np.uint32)
+    rows = np.repeat(np.arange(trials), size)
+    for i in range(b):
+        pos = np_hash_u32(sets.ravel(), i) % np.uint32(words * 32)
+        np.bitwise_or.at(out, (rows, pos >> 5),
+                         np.uint32(1) << (pos & 31).astype(np.uint32))
+    return out
+
+
+@pytest.mark.parametrize("inter,words,b", [(100, 32, 2), (400, 116, 2),
+                                           (50, 4, 2)])
+def test_bf_and_rmse_matches_bloom_trials(inter, words, b):
+    """``bf_and_rmse`` is Prop IV.1's RMSE of |X∩Y|_AND: over 300 seeded
+    pairs it lies within 20 % of the empirical RMSE (the sampling error of
+    an RMSE over 300 trials is about 4 %). Fill c·b/B runs from 0.2 to
+    0.78. Each set holds a tenth of |X∩Y| of its own; cross-collisions of
+    those private bits bias the AND upwards, which the formula leaves out."""
+    x, y = _trial_pairs(inter * 7 + words, inter, inter // 10)
+    est = np.asarray(E.bf_intersection_and(
+        jnp.asarray(_bloom_rows(x, words, b)),
+        jnp.asarray(_bloom_rows(y, words, b)), b))
+    empirical = float(np.sqrt(np.mean((est - inter) ** 2)))
+    scale = float(bounds.bf_and_rmse(inter, words * 32, b))
+    assert 0.8 <= empirical / scale <= 1.25, (empirical, scale)
+
+
+@pytest.mark.parametrize("size", [128, 512])
+@pytest.mark.parametrize("kind", ["kh", "1h"])
+def test_minhash_error_scale_exceeded_at_most_delta(kind, size):
+    """``minhash_error_scale(size, k, δ)`` inverts Prop IV.2 (and IV.3, of
+    the same form): the |X∩Y| estimate of two sets of ``size`` elements
+    each, half shared, misses by more than it in at most a share δ of
+    trials. Tolerance: three binomial standard errors over 300 trials."""
+    k, delta, n = 64, 0.05, 1 << 30
+    inter = size // 2
+    x, y = _trial_pairs(size + len(kind), inter, size - inter)
+    rx = jnp.asarray(np.sort(x, axis=1).astype(np.int32))
+    ry = jnp.asarray(np.sort(y, axis=1).astype(np.int32))
+    sizes = jnp.full(TRIALS, size, jnp.int32)
+
+    @jax.jit
+    def estimate(rx, ry):
+        if kind == "kh":
+            return E.khash_intersection(S.khash_rows(rx, n, k),
+                                        S.khash_rows(ry, n, k), sizes, sizes, n)
+        mx, my = S.onehash_rows(rx, n, k), S.onehash_rows(ry, n, k)
+        return E.onehash_intersection(mx, my, S.onehash_values(mx, n),
+                                      S.onehash_values(my, n), sizes, sizes, n)
+
+    est = estimate(rx, ry)
+    t = float(bounds.minhash_error_scale(size, k, delta))
+    share = float(np.mean(np.abs(np.asarray(est) - inter) > t))
+    assert share <= delta + 3 * np.sqrt(delta * (1 - delta) / TRIALS), share

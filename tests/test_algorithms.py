@@ -10,6 +10,7 @@ from repro.core import graph as G, sketches as S, exact as X
 from repro.core import (triangle_count, four_clique_count, jarvis_patrick,
                         pair_similarity, link_prediction_effectiveness)
 from repro.core.algorithms.clustering import _connected_components, _keep_mask
+from repro.core.algorithms.linkpred import split_edges
 from repro.core.algorithms.similarity import similarity_from_cardinalities
 from repro.core.algorithms.tc import local_clustering_coefficient
 
@@ -242,3 +243,63 @@ def test_link_prediction_with_sketch(gk):
     ef = link_prediction_effectiveness(gk, "common", removed_fraction=0.05,
                                        sketch_kind="bf", storage_budget=0.5, seed=3)
     assert ef > 0.005
+
+
+# ---------------------------------------------------------------------------
+# exact intersection elements (Adamic-Adar / Resource-Allocation oracle)
+# ---------------------------------------------------------------------------
+
+ELEMENT_GRAPHS = {"erdos_renyi": lambda: G.erdos_renyi(250, 0.06, seed=11),
+                  "kronecker": lambda: G.kronecker(9, 12, seed=4),
+                  "barabasi_albert": lambda: G.barabasi_albert(300, 5, seed=2)}
+
+
+@pytest.mark.parametrize("name", list(ELEMENT_GRAPHS))
+def test_exact_pair_intersection_elements_match_numpy(name):
+    """Each row holds exactly N_u ∩ N_v (``np.intersect1d``), padded with
+    n, for edges, random pairs and u == u."""
+    g = ELEMENT_GRAPHS[name]()
+    rng = np.random.default_rng(5)
+    pairs = np.concatenate([np.asarray(g.edges)[:200],
+                            rng.integers(0, g.n, size=(100, 2)),
+                            np.stack([np.arange(20)] * 2, axis=1)]).astype(np.int32)
+    out = np.asarray(jax.jit(X.exact_pair_intersection_elements)(
+        g, jnp.asarray(pairs)))
+    assert out.shape == (pairs.shape[0], g.d_max)
+    for (u, v), row in zip(pairs, out):
+        assert np.all(row <= g.n)
+        np.testing.assert_array_equal(
+            np.sort(row[row < g.n]),
+            np.intersect1d(G.neighbors_np(g, u), G.neighbors_np(g, v)))
+
+
+# ---------------------------------------------------------------------------
+# link prediction's edge split (paper Listing 5)
+# ---------------------------------------------------------------------------
+
+def test_split_edges_removes_the_fraction_asked(gk):
+    for fraction in (0.05, 0.3):
+        sparse, removed = split_edges(gk, fraction, seed=1)
+        r = max(1, int(fraction * gk.m))
+        assert removed.shape == (r, 2)
+        assert sparse.m == gk.m - r and sparse.n == gk.n
+
+
+def test_split_edges_partitions_the_edge_set(gk):
+    """Removed and kept edges are disjoint and together are the graph's."""
+    sparse, removed = split_edges(gk, 0.2, seed=2)
+    kept = {tuple(e) for e in np.asarray(sparse.edges).tolist()}
+    gone = {tuple(e) for e in removed.tolist()}
+    assert len(gone) == removed.shape[0]
+    assert not kept & gone
+    assert kept | gone == {tuple(e) for e in np.asarray(gk.edges).tolist()}
+
+
+def test_split_edges_is_seeded(gk):
+    a_sparse, a_removed = split_edges(gk, 0.1, seed=7)
+    b_sparse, b_removed = split_edges(gk, 0.1, seed=7)
+    _, c_removed = split_edges(gk, 0.1, seed=8)
+    np.testing.assert_array_equal(a_removed, b_removed)
+    np.testing.assert_array_equal(np.asarray(a_sparse.edges),
+                                  np.asarray(b_sparse.edges))
+    assert not np.array_equal(a_removed, c_removed)

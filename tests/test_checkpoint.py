@@ -7,6 +7,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import (save_checkpoint, restore_checkpoint, latest_step,
                               AsyncCheckpointer)
+from repro.checkpoint.store import load_meta
 
 
 def _tree():
@@ -69,3 +70,22 @@ def test_elastic_restore_with_new_sharding(tmp_path):
     out = restore_checkpoint(d, 5, tree, shardings)
     np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(tree["w"]))
     assert out["w"].sharding == shardings["w"]
+
+
+def test_load_meta_reads_shapes_without_arrays(tmp_path):
+    """``load_meta`` gives every saved leaf's shape and dtype from
+    meta.json alone: it still answers once the array files are gone."""
+    d = str(tmp_path / "ckpt")
+    path = save_checkpoint(d, 3, _tree())
+    for name in os.listdir(path):
+        if name.endswith(".npy"):
+            os.remove(os.path.join(path, name))
+    meta = load_meta(d, 3)
+    assert meta["step"] == 3
+    want = {"params__w": ([3, 4], "float32"),
+            "params__blocks__idx0__a": ([5], "float32"),
+            "params__blocks__idx1__a": ([2], "float32"),
+            "step": ([], "int32")}
+    assert {key: (leaf["shape"], leaf["dtype"])
+            for key, leaf in meta["leaves"].items()} == want
+

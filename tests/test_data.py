@@ -1,45 +1,8 @@
-"""Data pipeline determinism + MinHash dedup (the paper inside the LM stack)."""
+"""MinHash near-duplicate document dedup."""
 import numpy as np
 
-from repro.data import SyntheticLMData, TokenBatcher, minhash_dedup, document_sketches
+from repro.data import minhash_dedup, document_sketches
 from repro.data.dedup import jaccard_estimate, k_for
-
-
-def test_pipeline_deterministic():
-    d1 = SyntheticLMData(vocab_size=100, seq_len=32, seed=3)
-    d2 = SyntheticLMData(vocab_size=100, seq_len=32, seed=3)
-    b1 = d1.batch(5, 8)
-    b2 = d2.batch(5, 8)
-    np.testing.assert_array_equal(b1["inputs"], b2["inputs"])
-    b3 = d1.batch(6, 8)
-    assert not np.array_equal(b1["inputs"], b3["inputs"])
-
-
-def test_pipeline_labels_shifted():
-    d = SyntheticLMData(vocab_size=50, seq_len=16, seed=0)
-    b = d.batch(0, 4)
-    assert b["inputs"].shape == (4, 16) and b["labels"].shape == (4, 16)
-
-
-def test_pipeline_is_learnable():
-    """Order-2 structure: next-token entropy far below uniform."""
-    d = SyntheticLMData(vocab_size=1000, seq_len=64, seed=1, branch=2)
-    b = d.batch(0, 64)
-    # bigram count: given (mode unknown) the branch=2 table bounds entropy
-    pairs = {}
-    for row in np.concatenate([b["inputs"], b["labels"][:, -1:]], axis=1):
-        for a, c in zip(row[:-1], row[1:]):
-            pairs.setdefault(int(a), set()).add(int(c))
-    avg_branch = np.mean([len(v) for v in pairs.values()])
-    assert avg_branch < 32  # << vocab 1000
-
-
-def test_token_batcher():
-    docs = [np.arange(10), np.arange(100, 130)]
-    tb = TokenBatcher(docs, seq_len=8)
-    assert tb.num_batches(2) == 2
-    b = tb.batch(0, 2)
-    assert b["inputs"].shape == (2, 8)
 
 
 def _doc(rng, n=400):

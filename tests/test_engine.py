@@ -73,6 +73,67 @@ def test_fold_and_map_chunking_equivalence(g, sk):
         np.testing.assert_allclose(float(total), float(jnp.sum(base)), rtol=1e-5)
 
 
+@pytest.mark.parametrize("kind", [None, "bf", "kh", "1h", "kmv"])
+def test_sum_edge_cardinalities_is_sum_of_cards(g, kind):
+    """The TC numerator's fold, hub-ordered and in 64-edge chunks, equals
+    the sum of the per-edge cardinalities in the graph's order (exactly
+    for exact counts, to float32 rounding for sketch estimates)."""
+    sketch = None if kind is None else S.build(g, kind, 0.33, num_hashes=2,
+                                               seed=1, k=16)
+    plan = eng.EnginePlan(edge_chunk=64, degree_order=True)
+    assert g.m % 64 != 0
+    total = float(eng.sum_edge_cardinalities(g, sketch, plan))
+    cards = np.asarray(eng.edge_cardinalities(
+        g, sketch, plan.with_(degree_order=False)), np.float64)
+    assert cards.shape == (g.m,)
+    if kind is None:
+        assert total == cards.sum()
+    else:
+        np.testing.assert_allclose(total, cards.sum(), rtol=1e-5)
+
+
+@pytest.fixture(scope="module")
+def exact_cards(g):
+    """The exact per-pair cardinality function and its masked chunk sum,
+    built once so the folds of every case share their compiled scans."""
+    fn = jax.jit(eng.pair_cardinality_fn(g, None, eng.EnginePlan()))
+
+    def chunk_fn(pairs, mask):
+        return jnp.sum(jnp.where(mask, fn(pairs), 0.0))
+
+    return fn, chunk_fn
+
+
+@pytest.mark.parametrize("m", [40, 3 * 64, 150])
+def test_fold_edges_masked_equals_fold_edges(g, exact_cards, m):
+    """On chunk-padded edges whose pad rows are real pairs, the masked fold
+    counts only the rows its mask keeps: it equals ``fold_edges`` on the
+    unpadded edges (m below one chunk, m = 3 chunks, m ragged)."""
+    chunk = 64
+    plan = eng.EnginePlan(edge_chunk=chunk)
+    fn, chunk_fn = exact_cards
+    edges = g.edges[:m]
+    pad = (-m) % chunk
+    padded = jnp.concatenate([edges, g.edges[m:m + pad]])
+    mask = jnp.arange(m + pad) < m
+    got = float(eng.fold_edges_masked(padded, mask, chunk_fn, plan))
+    want = float(eng.fold_edges(edges, chunk_fn, plan))
+    assert got == want == float(np.sum(np.asarray(fn(edges))))
+    assert pad == 0 or float(np.sum(np.asarray(fn(padded)))) > want
+
+
+@pytest.mark.parametrize("lo", [1, 8, 64])
+def test_pow2_bucket_is_smallest_power_of_two(lo):
+    """``pow2_bucket(size, lo)``, for the power-of-two floors its callers
+    pass, is the smallest power of two that is at least ``max(size, lo)``:
+    one shape per octave of sizes keeps padded batches from compiling a
+    program per size."""
+    for size in range(0, 5000):
+        b = eng.pow2_bucket(size, lo)
+        need = max(size, lo, 1)
+        assert b & (b - 1) == 0 and b >= need and b // 2 < need, (size, lo, b)
+
+
 def test_degree_order_is_a_permutation(g):
     edges_s, inv = eng.order_edges_by_hub(g, g.edges)
     # same multiset of edges, and inv restores the original order

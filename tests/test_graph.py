@@ -1,7 +1,10 @@
-"""Graph model regressions: empty graphs, generator edge distributions."""
+"""Graph model regressions: empty graphs, generator edge distributions,
+canonical edge identity."""
 import numpy as np
+import pytest
 
 from repro.core import graph as G
+from repro.stream.dynamic_graph import DynamicGraph
 
 
 def test_from_edge_array_n0_returns_valid_empty_graph():
@@ -53,3 +56,65 @@ def test_erdos_renyi_large_n_geometric_skipping():
     e = np.asarray(g.edges)
     assert (e[:, 0] < e[:, 1]).all()                 # canonical, no self loops
     assert e[:, 1].max() < n
+
+
+# ---------------------------------------------------------------------------
+# canonical_edge_keys: the edge identity from_edge_array and DynamicGraph share
+# ---------------------------------------------------------------------------
+
+def test_canonical_edge_keys_drops_self_loops():
+    keys = G.canonical_edge_keys(10, np.array([[3, 3], [2, 5], [7, 7], [0, 0]]))
+    np.testing.assert_array_equal(keys, [2 * 10 + 5])
+
+
+def test_canonical_edge_keys_drops_out_of_range_endpoints():
+    raw = np.array([[-1, 4], [4, -1], [2, 10], [10, 2], [11, 12], [1, 9]])
+    np.testing.assert_array_equal(G.canonical_edge_keys(10, raw), [1 * 10 + 9])
+
+
+def test_canonical_edge_keys_folds_both_directions():
+    """u→v and v→u are one key, lo·n + hi, and keys come out sorted and
+    unique whatever the input order and multiplicity."""
+    raw = np.array([[6, 1], [1, 6], [6, 1], [4, 2], [2, 4], [0, 3]])
+    np.testing.assert_array_equal(G.canonical_edge_keys(7, raw),
+                                  [0 * 7 + 3, 1 * 7 + 6, 2 * 7 + 4])
+
+
+@pytest.mark.parametrize("n,edges", [(0, np.array([[0, 1], [1, 0]])),
+                                     (5, None)])
+def test_canonical_edge_keys_empty(n, edges):
+    keys = G.canonical_edge_keys(n, edges)
+    assert keys.shape == (0,) and keys.dtype == np.int64
+
+
+def _raw_edges(seed, n=60, m=400):
+    """Random endpoints with duplicates, both directions, self loops and
+    out-of-range ids mixed in."""
+    rng = np.random.default_rng(seed)
+    e = rng.integers(0, n, size=(m, 2))
+    e = np.concatenate([e, e[:50, ::-1], e[:30], np.stack([np.arange(10)] * 2, 1),
+                        [[n, 1], [-2, 3]]])
+    return n, e[rng.permutation(e.shape[0])]
+
+
+def _edge_set(edges):
+    return {tuple(int(x) for x in row) for row in np.asarray(edges)}
+
+
+@pytest.mark.parametrize("via", ["from_edges", "apply_delta"])
+def test_static_and_streaming_graphs_agree_on_edges(via):
+    """``from_edge_array`` and ``DynamicGraph`` read the same raw edges as
+    the same canonical edge set, and the same degrees and rows."""
+    n, raw = _raw_edges(3)
+    g = G.from_edge_array(n, raw)
+    if via == "from_edges":
+        dyn = DynamicGraph.from_edges(n, raw)
+    else:
+        dyn = DynamicGraph.from_edges(n, None)
+        assert dyn.m == 0
+        dyn.apply_delta(inserts=raw)
+    assert _edge_set(dyn.edge_array()) == _edge_set(g.edges)
+    assert dyn.m == g.m
+    np.testing.assert_array_equal(dyn.deg, np.asarray(g.deg))
+    for v in range(n):
+        np.testing.assert_array_equal(dyn.neighbors(v), G.neighbors_np(g, v))

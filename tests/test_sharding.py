@@ -1,10 +1,6 @@
-"""Logical-axis sharding rules + HLO analyzer unit tests."""
-import numpy as np
-import jax
-import jax.numpy as jnp
+"""Edge-axis sharding rules: logical axes -> mesh axes."""
 from jax.sharding import PartitionSpec as P
 
-from repro.analysis import hlo as H
 from repro.distributed import sharding as SH
 
 
@@ -14,108 +10,52 @@ class _FakeMesh:
 
 
 def test_spec_filters_nondivisible_dims():
-    mesh = _FakeMesh({"data": 16, "model": 16})
-    spec = SH.spec_for(("batch", "seq", "vocab"), (1, 1, 32000), mesh=mesh,
-                       rules=SH.BASE_RULES)
-    assert spec == P(None, None, "model")  # batch=1 cannot shard; vocab can
-    spec = SH.spec_for(("batch", "seq", "vocab"), (256, 4096, 50280), mesh=mesh,
-                       rules=SH.BASE_RULES)
-    assert spec[0] == "data" and spec[2] is None  # 50280 % 16 != 0
+    mesh = _FakeMesh({"pod": 2, "data": 4, "model": 2})
+    # 64 edges split over all three axes (2 * 4 * 2 = 16 shards)
+    assert SH.spec_for(("edge",), (64,), mesh=mesh) == P(("pod", "data", "model"))
+    # 12 edges: "pod" divides, "data" would make 8 shards and is dropped,
+    # "model" makes 4 and is kept
+    assert SH.spec_for(("edge", None), (12, 2), mesh=mesh) == P(("pod", "model"), None)
+    # an odd edge count takes no axis
+    assert SH.spec_for(("edge",), (7,), mesh=mesh) == P(None)
+    # rule axes the mesh lacks are skipped
+    assert SH.spec_for(("edge",), (8,), mesh=_FakeMesh({"data": 4})) == P("data")
 
 
 def test_spec_never_reuses_mesh_axis():
-    mesh = _FakeMesh({"data": 16, "model": 16})
-    # heads takes 'model'; head_dim_tp must not reuse it
-    spec = SH.spec_for(("embed_fsdp", "heads", "head_dim_tp"),
-                       (4096, 32, 128), mesh=mesh, rules=SH.BASE_RULES)
-    assert spec == P("data", "model", None)
-    # MQA fallback: heads=8 can't take 16-way 'model'; head_dim 256 can
-    spec = SH.spec_for(("embed_fsdp", "heads", "head_dim_tp"),
-                       (2048, 8, 256), mesh=mesh, rules=SH.BASE_RULES)
-    assert spec == P("data", None, "model")
+    mesh = _FakeMesh({"data": 4, "model": 2})
+    rules = {"edge": ("data", "model"), "word": "model"}
+    # "edge" takes both axes; "word" must not reuse "model"
+    assert SH.spec_for(("edge", "word"), (8, 8), mesh=mesh,
+                       rules=rules) == P(("data", "model"), None)
+    # 12 edges cannot take 4 * 2 shards, so "model" stays free for "word"
+    assert SH.spec_for(("edge", "word"), (12, 8), mesh=mesh,
+                       rules=rules) == P("data", "model")
+    # without dim sizes the first claim wins outright
+    assert SH.spec_for(("edge", "edge"), mesh=mesh) == P(("data", "model"), None)
 
 
-def test_shard_as_noop_without_mesh():
-    x = jnp.ones((4, 4))
-    assert SH.shard_as(x, "batch", None) is x
+def test_use_rules_installs_and_restores_mesh():
+    """``use_rules`` installs the mesh the engine's edge split reads, per
+    thread and nested, and puts the previous one back on exit, also when
+    the block raises."""
+    import threading
 
-
-def test_hlo_analyzer_dot_flops():
-    txt = """
-HloModule test
-
-ENTRY %main (p0: f32[64,128], p1: f32[128,32]) -> f32[64,32] {
-  %p0 = f32[64,128]{1,0} parameter(0)
-  %p1 = f32[128,32]{1,0} parameter(1)
-  ROOT %dot.1 = f32[64,32]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-}
-"""
-    stats = H.analyze(txt)
-    assert stats.flops == 2 * 64 * 128 * 32
-
-
-def test_hlo_analyzer_while_scaling():
-    txt = """
-HloModule test
-
-%body (p: (s32[], f32[8,8])) -> (s32[], f32[8,8]) {
-  %p = (s32[], f32[8,8]) parameter(0)
-  %g0 = s32[] get-tuple-element(%p), index=0
-  %g1 = f32[8,8]{1,0} get-tuple-element(%p), index=1
-  %dot.2 = f32[8,8]{1,0} dot(%g1, %g1), lhs_contracting_dims={1}, rhs_contracting_dims={0}
-  ROOT %t = (s32[], f32[8,8]) tuple(%g0, %dot.2)
-}
-
-%cond (p2: (s32[], f32[8,8])) -> pred[] {
-  %p2 = (s32[], f32[8,8]) parameter(0)
-  %g = s32[] get-tuple-element(%p2), index=0
-  %c = s32[] constant(12)
-  ROOT %lt = pred[] compare(%g, %c), direction=LT
-}
-
-ENTRY %main (a: f32[8,8]) -> f32[8,8] {
-  %a = f32[8,8]{1,0} parameter(0)
-  %z = s32[] constant(0)
-  %t0 = (s32[], f32[8,8]) tuple(%z, %a)
-  %w = (s32[], f32[8,8]) while(%t0), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"12"}}
-  ROOT %out = f32[8,8]{1,0} get-tuple-element(%w), index=1
-}
-"""
-    stats = H.analyze(txt)
-    assert stats.flops == 12 * 2 * 8 * 8 * 8
-    assert stats.while_trip_counts == [12]
-
-
-def test_hlo_analyzer_collectives():
-    txt = """
-HloModule test
-
-%add (x: f32[], y: f32[]) -> f32[] {
-  %x = f32[] parameter(0)
-  %y = f32[] parameter(1)
-  ROOT %a = f32[] add(%x, %y)
-}
-
-ENTRY %main (p: f32[1024]) -> f32[1024] {
-  %p = f32[1024]{0} parameter(0)
-  ROOT %ar = f32[1024]{0} all-reduce(%p), replica_groups=[2,4]<=[8], to_apply=%add
-}
-"""
-    stats = H.analyze(txt)
-    assert stats.collective_bytes["all-reduce"] == 4096
-    # ring model: 2(g-1)/g * bytes with g=4
-    assert abs(stats.collective_link_bytes - 2 * 3 / 4 * 4096) < 1e-6
-
-
-def test_roofline_terms():
-    from repro.analysis import roofline as R
-    from repro import configs as C
-    from repro.models import SHAPES
-    stats = H.HloStats(flops=1.97e14, bytes_proxy=8.19e11,
-                       collective_link_bytes=5e10)
-    roof = R.build("qwen3_8b", SHAPES["train_4k"], C.get("qwen3_8b"),
-                   "16x16", 256, stats)
-    assert abs(roof.compute_s - 1.0) < 1e-6
-    assert abs(roof.memory_s - 1.0) < 1e-6
-    assert abs(roof.collective_s - 1.0) < 1e-6
-    assert roof.bottleneck in ("compute", "memory", "collective")
+    outer, inner = _FakeMesh({"data": 2}), _FakeMesh({"data": 4})
+    assert SH.active_mesh() is None
+    with SH.use_rules(outer):
+        assert SH.active_mesh() is outer
+        seen = []
+        worker = threading.Thread(target=lambda: seen.append(SH.active_mesh()))
+        worker.start()
+        worker.join()
+        assert seen == [None]
+        try:
+            with SH.use_rules(inner):
+                assert SH.active_mesh() is inner
+                assert SH.spec_for(("edge",), (8,)) == P("data")
+                raise RuntimeError
+        except RuntimeError:
+            pass
+        assert SH.active_mesh() is outer
+    assert SH.active_mesh() is None

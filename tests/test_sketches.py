@@ -2,11 +2,14 @@
 import functools
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import graph as G, sketches as S
-from repro.core.hashing import hash_u32, np_hash_u32
+from repro.core.hashing import (fmix32, hash_u32, hash_unit_interval,
+                                np_fmix32, np_hash_u32)
+from repro.stream.dynamic_graph import DynamicGraph
 
 
 def test_hash_np_jax_twins():
@@ -108,6 +111,87 @@ def test_edge_list_bloom_pair_sort_equals_key_sort(bloom_graph, b):
                                seed=5, one_key=False)
     assert np.array_equal(np.asarray(pair),
                           np.asarray(S.build_bloom(g, 8, b, 5)))
+
+
+ROW_GRAPHS = {"kronecker": lambda: G.kronecker(8, 8, seed=4),
+              "erdos_renyi": lambda: G.erdos_renyi(200, 0.06, seed=9),
+              "star": _star}
+
+
+@pytest.mark.parametrize("graph_name", list(ROW_GRAPHS))
+@pytest.mark.parametrize("kind", ["kh", "1h", "kmv"])
+def test_row_builders_equal_build_rows(kind, graph_name):
+    """The invariant behind the dirty-row rebuilds: the per-row builder on
+    a subset of vertices at a ``DynamicGraph``'s capacity, wider than the
+    graph's ``d_max``, gives those rows of ``build(graph, kind)``. The
+    subset holds the largest row (in the star it fills ``d_max``: no pad
+    in the build, pads in the rebuild) and the smallest."""
+    g = ROW_GRAPHS[graph_name]()
+    k, seed = 8, 3
+    full = np.asarray(S.build(g, kind, k=k, seed=seed).data)
+    row_fn = {"kh": S.khash_rows, "1h": S.onehash_rows, "kmv": S.kmv_rows}[kind]
+    deg = np.asarray(g.deg)
+    rng = np.random.default_rng(len(graph_name))
+    verts = np.concatenate([[np.argmax(deg), np.argmin(deg)],
+                            rng.choice(g.n, 16, replace=False)])
+    assert deg[verts[0]] == g.d_max
+    wide = DynamicGraph.from_graph(g).adj
+    assert wide.shape[1] > g.d_max
+    rebuild = jax.jit(row_fn, static_argnames=("n", "k", "seed"))
+    got = np.asarray(rebuild(jnp.asarray(wide[verts]), n=g.n, k=k, seed=seed))
+    np.testing.assert_array_equal(got, full[verts])
+
+
+TWIN_SEEDS = [0, 1, 12345, 2**31 - 1]
+
+
+def _uint32_keys(seed):
+    """The uint32 corners and 4,096 seeded keys over the whole range."""
+    rng = np.random.default_rng(seed)
+    corners = np.array([0, 1, 2**31 - 1, 2**31, 2**32 - 1], np.uint32)
+    return np.concatenate([corners, rng.integers(0, 2**32, size=4096,
+                                                 dtype=np.uint32)])
+
+
+@pytest.mark.parametrize("seed", TWIN_SEEDS)
+def test_fmix32_host_twin_bit_identical(seed):
+    """``np_fmix32`` is ``fmix32`` on the host, bit for bit, over the whole
+    uint32 range; the finalizer is a bijection, so distinct keys stay
+    distinct."""
+    x = _uint32_keys(seed)
+    got = np.asarray(fmix32(jnp.asarray(x)))
+    assert np.array_equal(got, np_fmix32(x))
+    assert np.unique(got).size == np.unique(x).size
+
+
+@pytest.mark.parametrize("seed", TWIN_SEEDS)
+def test_hash_u32_host_twin_bit_identical(seed):
+    """``np_hash_u32`` is ``hash_u32`` on the host at the same seed, for
+    uint32 keys and for the int32 vertex ids the sketch builders pass:
+    the host builds and the benchmark's numpy reference set the bits the
+    device does."""
+    x = _uint32_keys(seed)
+    assert np.array_equal(np.asarray(hash_u32(jnp.asarray(x), seed)),
+                          np_hash_u32(x, seed))
+    ids = (x % np.uint32(2**31)).astype(np.int32)
+    assert np.array_equal(np.asarray(hash_u32(jnp.asarray(ids), seed)),
+                          np_hash_u32(ids, seed))
+
+
+@pytest.mark.parametrize("seed", [0, 7])
+def test_hash_unit_interval_is_scaled_hash(seed):
+    """KMV's hash: (h + 1) / 2**32 in float32 for the seeded 32-bit hash
+    h, so every value lies in (0, 1] and the order of values follows the
+    order of hashes."""
+    x = _uint32_keys(seed)
+    got = np.asarray(hash_unit_interval(jnp.asarray(x), seed))
+    h = np_hash_u32(x, seed)
+    want = (h.astype(np.float32) + np.float32(1)) * np.float32(2.0 ** -32)
+    assert got.dtype == np.float32
+    assert np.array_equal(got, want)
+    assert np.all(got > 0) and np.all(got <= 1)
+    order = np.argsort(h, kind="stable")
+    assert np.all(np.diff(got[order]) >= 0)
 
 
 def test_bloom_membership_no_false_negatives(g):
