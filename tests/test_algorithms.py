@@ -9,10 +9,13 @@ import pytest
 from repro.core import graph as G, sketches as S, exact as X
 from repro.core import (triangle_count, four_clique_count, jarvis_patrick,
                         pair_similarity, link_prediction_effectiveness)
-from repro.core.algorithms.clustering import _connected_components, _keep_mask
+from repro.core.algorithms.clustering import (_connected_components,
+                                             _keep_mask, _kept_components,
+                                             _kept_first)
 from repro.core.algorithms.linkpred import split_edges
 from repro.core.algorithms.similarity import similarity_from_cardinalities
 from repro.core.algorithms.tc import local_clustering_coefficient
+from repro.engine import pow2_bucket
 
 
 @pytest.fixture(scope="module")
@@ -131,28 +134,38 @@ def _path_edges(n, seed=None):
 
 
 _CC_CASES = {
-    # name: (n, edges, keep, max_iters); None keeps all edges
+    # name: (n, edges, keep, max_iters); keep as ``_cc_keep`` reads it
     "random_keep_all": (300, _random_edges(300, 260, 1), None, 200),
     "random_keep_none": (300, _random_edges(300, 260, 1), False, 200),
     "random_keep_random": (300, _random_edges(300, 400, 2), "random", 200),
+    "random_keep_last": (300, _random_edges(300, 260, 1), "last", 200),
     "isolated_vertex": (7, np.array([[0, 1], [1, 2], [2, 4], [5, 6]],
                                     np.int32), None, 200),
     "long_path": (1500, _path_edges(1500), None, 200),
     "shuffled_path": (256, _path_edges(256, 3), None, 200),
+    "shuffled_path_keep_sparse": (1500, _path_edges(1500, 3), "sparse", 200),
+    "pow2_path": (257, _path_edges(257, 6), None, 200),
     "capped_early": (1500, _path_edges(1500, 3), None, 3),
 }
+
+
+def _cc_keep(keep, m):
+    """bool[m] from a case's keep: None all, False none, "random" 60 % and
+    "sparse" 30 % of the edges (seeded), "last" the last 10 edges."""
+    if keep is None:
+        return np.ones(m, bool)
+    if keep is False:
+        return np.zeros(m, bool)
+    if keep == "last":
+        return np.arange(m) >= m - 10
+    share = {"random": 0.6, "sparse": 0.3}[keep]
+    return np.random.default_rng(4).random(m) < share
 
 
 @pytest.mark.parametrize("case", sorted(_CC_CASES))
 def test_label_propagation_matches_numpy(case):
     n, edges, keep, max_iters = _CC_CASES[case]
-    m = edges.shape[0]
-    if keep is None:
-        keep = np.ones(m, bool)
-    elif keep is False:
-        keep = np.zeros(m, bool)
-    else:
-        keep = np.random.default_rng(4).random(m) < 0.6
+    keep = _cc_keep(keep, edges.shape[0])
     labels, num, iters = _connected_components(
         n, jnp.asarray(edges), jnp.asarray(keep), max_iters=max_iters)
     want, want_iters = _label_propagation_np(n, edges, keep, max_iters)
@@ -172,6 +185,51 @@ def test_label_propagation_matches_numpy(case):
         # scatter-min alone moves a label one hop a round; the pointer jump
         # crosses the 1,499-edge chain in a few dozen
         assert 2 < want_iters < 64
+
+
+# the loop's rows for cases that pin each regime of min(pow2_bucket(kept), m)
+_CC_ROWS = {
+    "random_keep_none": 1,             # kept 0
+    "random_keep_all": 259,            # kept 259 = m: 512 clamped to m
+    "pow2_path": 256,                  # kept 256 = m = pow2_bucket(256)
+    "random_keep_random": 256,         # kept 217 < 256 < m 399
+    "random_keep_last": 16,            # kept 10 < 16 < m 259
+    "shuffled_path_keep_sparse": 512,  # kept 450 < 512 < m 1,499
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CC_CASES))
+def test_kept_prefix_equals_full_edge_loop(case):
+    """``jarvis_patrick``'s loop path (kept-first sort, host count, loop on
+    a pow2 prefix) gives the labels, cluster count and iteration count of
+    the loop over all edges and of the numpy replay; the non-kept rows
+    inside the prefix are no-ops."""
+    n, edges, keep, max_iters = _CC_CASES[case]
+    m = edges.shape[0]
+    keep = _cc_keep(keep, m)
+    kept = int(keep.sum())
+    sorted_edges, sorted_keep, count = _kept_first(jnp.asarray(edges),
+                                                   jnp.asarray(keep))
+    # stable: kept edges first, each group in its canonical order
+    np.testing.assert_array_equal(np.asarray(sorted_edges),
+                                  np.concatenate([edges[keep], edges[~keep]]))
+    np.testing.assert_array_equal(np.asarray(sorted_keep), np.arange(m) < kept)
+    assert int(count) == kept
+
+    labels, num, iters, got_kept, rows = _kept_components(
+        n, jnp.asarray(edges), jnp.asarray(keep), max_iters)
+    assert got_kept == kept
+    assert rows == min(pow2_bucket(kept), m)
+    assert kept <= rows <= m
+    if case in _CC_ROWS:
+        assert rows == _CC_ROWS[case]
+    full_labels, full_num, full_iters = _connected_components(
+        n, jnp.asarray(edges), jnp.asarray(keep), max_iters=max_iters)
+    want, want_iters = _label_propagation_np(n, edges, keep, max_iters)
+    np.testing.assert_array_equal(np.asarray(labels), np.asarray(full_labels))
+    np.testing.assert_array_equal(np.asarray(labels), want)
+    assert int(iters) == int(full_iters) == want_iters
+    assert int(num) == int(full_num) == int(np.sum(want == np.arange(n)))
 
 
 @pytest.mark.parametrize("similarity,threshold",

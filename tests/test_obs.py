@@ -559,6 +559,25 @@ def test_jarvis_patrick_compiles_once_across_sessions(tracing):
     assert int(first[1]) == int(second[1])
 
 
+def test_label_propagation_span_counts_kept_edges(tracing):
+    """``jp.label_propagation`` records the kept edges and the prefix of the
+    edge list the loop ran on: kept = sum(keep) ≤ bucket ≤ m."""
+    from repro import engine as eng
+    from repro.core.algorithms.clustering import _keep_mask
+
+    g = G.kronecker(7, 8, seed=2)
+    sess = eng.session(g, "bf", storage_budget=1.0)
+    jax.block_until_ready(sess.jarvis_patrick("jaccard", 0.05))
+    keep = _keep_mask(g.deg, g.edges, sess.edge_cardinalities(),
+                      jnp.asarray(0.05, jnp.float32), "jaccard")
+    (lp,) = [e for e in trace.events() if e["name"] == "jp.label_propagation"]
+    kept, bucket = lp["args"]["kept"], lp["args"]["bucket"]
+    assert isinstance(kept, int) and isinstance(bucket, int)
+    assert kept == int(jnp.sum(keep))
+    assert 0 < kept <= bucket <= g.m
+    assert bucket == min(eng.pow2_bucket(kept), g.m)
+
+
 # ---------------------------------------------------------------------------
 # bench record schema (benchmarks.common)
 # ---------------------------------------------------------------------------
